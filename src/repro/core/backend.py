@@ -290,7 +290,9 @@ class ConsensusBackend(abc.ABC):
         float, "flops": float}`` from the compiled (post-SPMD) HLO via
         ``repro.launch.hlo_analysis`` — counts include while-loop trip
         multipliers, so a K-iteration ADMM scan with one all-reduce per
-        iteration reports ``K`` all-reduces.  This is the assertion
+        iteration reports ``K`` all-reduces — plus ``memory_bytes``, the
+        arguments, outputs and temporaries the compiler allots the
+        program on each device.  This is the assertion
         surface for the collective-free hot path: a ``trace_every=0``
         program must contain only the policy's own exchanges.
 
@@ -308,11 +310,16 @@ class ConsensusBackend(abc.ABC):
         args = tuple(self.shard_workers(a) for a in stacked_args)
         compiled = jitted.lower(*args, *self._place_replicated(replicated)).compile()
         analysis = analyze_module(compiled.as_text())
+        mem = compiled.memory_analysis()
         return {
             "collective_counts": analysis.collective_counts(),
             "collective_wire_bytes": analysis.collective_wire_bytes,
             "collective_by_type": analysis.collective_by_type(),
             "flops": analysis.flops,
+            "memory_bytes": (
+                mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes
+            ),
         }
 
     def lowering_texts(

@@ -82,7 +82,40 @@ def _propagate_and_stats(w, y_m, t_m, mu: float, use_kernels: bool):
     return y_new, a, chol, jitter
 
 
-def fused_layer_step(
+class LayerProgram(NamedTuple):
+    """One layer's SPMD program bound to its operands, ready to run or to
+    lower through the backend's executable cache (same key either way, so
+    the texts and stats describe exactly the program :meth:`run` runs)."""
+
+    backend: ConsensusBackend
+    worker: object
+    y_workers: Array
+    t_workers: Array
+    replicated: tuple
+    key: tuple
+    donate: tuple
+    policy: ConsensusPolicy
+
+    def _call(self, method):
+        return method(
+            self.worker, self.y_workers, self.t_workers,
+            replicated=self.replicated, key=self.key, donate=self.donate,
+            policy=self.policy,
+        )
+
+    def run(self):
+        return self._call(self.backend.run)
+
+    def lowering_texts(self) -> dict:
+        """``ConsensusBackend.lowering_texts`` of this program."""
+        return self._call(self.backend.lowering_texts)
+
+    def lowering_stats(self) -> dict:
+        """``ConsensusBackend.lowering_stats`` of this program."""
+        return self._call(self.backend.lowering_stats)
+
+
+def layer_program(
     backend: ConsensusBackend,
     y_workers: Array,
     t_workers: Array,
@@ -95,36 +128,10 @@ def fused_layer_step(
     donate_y: bool = False,
     policy: ConsensusPolicy | None = None,
     trace_every: int = 1,
-) -> LayerStepResult:
-    """One dSSFN layer as a single cached SPMD program.
-
-    y_workers: (M, n_{l-1}, J_m) previous-layer features (layer input x at
-        l=0), stacked per worker.
-    w: replicated layer weight W_l = [V_Q O_{l-1} ; R_l], or None at l=0
-        (solve directly on the input features, no propagation).
-    donate_y: donate the stacked Y buffer to XLA (off-CPU) — pass True
-        only when the input Y is a buffer the engine itself materialized
-        (layers >= 2: the relu(W@Y) carry).  Layer 0's input is the
-        caller's array, and layer 0's pass-through output may alias it
-        (jit forwards unchanged inputs), so layer 1 must not donate
-        either.
-    policy: consensus strategy for the ADMM scan inside this program
-        (default: the backend's policy).  Part of the cache key — one
-        lowering per (layer shape, policy), never a per-call re-trace.
-        Gossip-family policies carry their ``Topology``, so the graph's
-        exchange schedule is compiled into this fused program and two
-        policies differing only in topology get distinct executables.
-    trace_every: convergence-trace stride for the ADMM scan
-        (``admm.worker_admm_iterations``): 1 = per-iteration traces
-        (default), 0 = the collective-free hot path (``result.trace`` is
-        None and the program contains only the policy's own exchanges),
-        N > 1 = every N-th iteration.  Part of the cache key — the value
-        changes the lowered program's output pytree.
-
-    The executable cache key covers every closed-over trace-affecting
-    value; W is an operand, so the (n, n)-shaped program compiled for
-    layer 2 is reused verbatim by layers 3..L.
-    """
+) -> LayerProgram:
+    """The program :func:`fused_layer_step` runs, unrun.  Operands may be
+    ``jax.ShapeDtypeStruct``s when the program is only lowered (simulated
+    backend).  Arguments as for :func:`fused_layer_step`."""
     m = y_workers.shape[0]
     if m != backend.num_workers:
         raise ValueError(
@@ -175,15 +182,67 @@ def fused_layer_step(
         w is not None,
         trace_every,
     )
-    (o_w, z_w, lam_w, y_next), traces, jitter_w = backend.run(
-        worker,
-        y_workers,
-        t_workers,
+    return LayerProgram(
+        backend=backend,
+        worker=worker,
+        y_workers=y_workers,
+        t_workers=t_workers,
         replicated=() if w is None else (w,),
         key=cache_key,
         donate=(0,) if donate_y else (),
         policy=policy,
     )
+
+
+def fused_layer_step(
+    backend: ConsensusBackend,
+    y_workers: Array,
+    t_workers: Array,
+    w: Array | None,
+    *,
+    mu: float,
+    eps_radius: float,
+    num_iters: int,
+    use_kernels: bool = False,
+    donate_y: bool = False,
+    policy: ConsensusPolicy | None = None,
+    trace_every: int = 1,
+) -> LayerStepResult:
+    """One dSSFN layer as a single cached SPMD program.
+
+    y_workers: (M, n_{l-1}, J_m) previous-layer features (layer input x at
+        l=0), stacked per worker.
+    w: replicated layer weight W_l = [V_Q O_{l-1} ; R_l], or None at l=0
+        (solve directly on the input features, no propagation).
+    donate_y: donate the stacked Y buffer to XLA (off-CPU) — pass True
+        only when the input Y is a buffer the engine itself materialized
+        (layers >= 2: the relu(W@Y) carry).  Layer 0's input is the
+        caller's array, and layer 0's pass-through output may alias it
+        (jit forwards unchanged inputs), so layer 1 must not donate
+        either.
+    policy: consensus strategy for the ADMM scan inside this program
+        (default: the backend's policy).  Part of the cache key — one
+        lowering per (layer shape, policy), never a per-call re-trace.
+        Gossip-family policies carry their ``Topology``, so the graph's
+        exchange schedule is compiled into this fused program and two
+        policies differing only in topology get distinct executables.
+    trace_every: convergence-trace stride for the ADMM scan
+        (``admm.worker_admm_iterations``): 1 = per-iteration traces
+        (default), 0 = the collective-free hot path (``result.trace`` is
+        None and the program contains only the policy's own exchanges),
+        N > 1 = every N-th iteration.  Part of the cache key — the value
+        changes the lowered program's output pytree.
+
+    The executable cache key covers every closed-over trace-affecting
+    value; W is an operand, so the (n, n)-shaped program compiled for
+    layer 2 is reused verbatim by layers 3..L.
+    """
+    (o_w, z_w, lam_w, y_next), traces, jitter_w = layer_program(
+        backend, y_workers, t_workers, w,
+        mu=mu, eps_radius=eps_radius, num_iters=num_iters,
+        use_kernels=use_kernels, donate_y=donate_y, policy=policy,
+        trace_every=trace_every,
+    ).run()
     trace = None
     if traces is not None:
         objs, primals, duals, cerrs = traces

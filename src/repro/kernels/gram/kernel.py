@@ -19,7 +19,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import default_interpret, tpu_compiler_params
+from repro.kernels.common import (
+    batch_leading,
+    default_interpret,
+    tpu_compiler_params,
+)
 
 
 def _gram_kernel(y1_ref, y2_ref, o_ref, acc_ref, *, inv_mu: float, nk: int, block_n: int):
@@ -61,7 +65,7 @@ def gram_pallas(
     kernel = functools.partial(
         _gram_kernel, inv_mu=1.0 / mu, nk=nk, block_n=block_n
     )
-    return pl.pallas_call(
+    return batch_leading(pl.pallas_call(
         kernel,
         grid=(n // block_n, n // block_n, nk),
         in_specs=[
@@ -73,4 +77,4 @@ def gram_pallas(
         scratch_shapes=[pltpu.VMEM((block_n, block_n), jnp.float32)],
         compiler_params=tpu_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(y, y)
+    ))(y, y)

@@ -14,7 +14,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import default_interpret, tpu_compiler_params
+from repro.kernels.common import (
+    batch_leading,
+    default_interpret,
+    tpu_compiler_params,
+)
 
 
 def _matmul_relu_kernel(w_ref, x_ref, o_ref, acc_ref, *, nk: int):
@@ -50,7 +54,7 @@ def matmul_relu_pallas(
     if interpret is None:
         interpret = default_interpret()
     nk = kdim // block_k
-    return pl.pallas_call(
+    return batch_leading(pl.pallas_call(
         functools.partial(_matmul_relu_kernel, nk=nk),
         grid=(m // block_m, n // block_n, nk),
         in_specs=[
@@ -62,4 +66,4 @@ def matmul_relu_pallas(
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         compiler_params=tpu_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(w, x)
+    ))(w, x)

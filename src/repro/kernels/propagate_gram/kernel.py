@@ -11,9 +11,11 @@ into an f32 VMEM accumulator — Y is read from HBM exactly once per layer
 and Y' is written exactly once, never re-read.
 
 Grid: (J/bj,) sequential over sample tiles.  W ((n, n_prev)) and the
-(n, n) accumulator stay VMEM-resident across the whole pass, which bounds
-the kernel to n*(n + n_prev)*4 bytes of VMEM (~8 MB at n = n_prev = 1024)
-— the dSSFN regime (n = 2Q + 1000) fits comfortably.
+(n, n) accumulator stay VMEM-resident across the whole pass.  Counting
+the pipeline's double buffers of W, G and the Y/Y' tiles, the kernel
+needs ``_vmem_bytes`` — 24 MiB with headroom at n = n_prev = 1024, over the 16 MiB
+default scoped limit a v5e compile enforces once the kernel is batched
+over workers — so the call raises its scoped limit to that need.
 """
 from __future__ import annotations
 
@@ -24,7 +26,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import default_interpret, tpu_compiler_params
+from repro.kernels.common import (
+    batch_leading,
+    default_interpret,
+    tpu_compiler_params,
+)
 
 
 def _propagate_gram_kernel(
@@ -52,6 +58,12 @@ def _propagate_gram_kernel(
         g_ref[...] = (acc_ref[...] + diag).astype(g_ref.dtype)
 
 
+def _vmem_bytes(n: int, n_prev: int, block_j: int) -> int:
+    """Scoped VMEM the pipeline needs: double-buffered W, G and Y/Y'
+    tiles plus the single (n, n) f32 accumulator, and 2 MiB headroom."""
+    return 4 * (2 * n * n_prev + 3 * n * n + 2 * (n + n_prev) * block_j) + (2 << 20)
+
+
 def propagate_gram_pallas(
     w: jax.Array,
     y: jax.Array,
@@ -77,7 +89,7 @@ def propagate_gram_pallas(
     kernel = functools.partial(
         _propagate_gram_kernel, inv_mu=1.0 / mu, nk=nk, n=n
     )
-    return pl.pallas_call(
+    return batch_leading(pl.pallas_call(
         kernel,
         grid=(nk,),
         in_specs=[
@@ -93,6 +105,8 @@ def propagate_gram_pallas(
             jax.ShapeDtypeStruct((n, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(("arbitrary",)),
+        compiler_params=tpu_compiler_params(
+            ("arbitrary",), vmem_limit_bytes=_vmem_bytes(n, n_prev, block_j)
+        ),
         interpret=interpret,
-    )(w, y)
+    ))(w, y)

@@ -18,7 +18,9 @@ So the dry-run walks the HLO text itself:
     gather/scatter/...) reads its operands and writes its result once;
   - collectives: result bytes -> wire bytes per device with ring formulas
     (all-gather (g-1)/g, all-reduce 2(g-1)/g, reduce-scatter (g-1),
-    all-to-all (g-1)/g, permute 1), scaled by the multiplier.
+    all-to-all (g-1)/g, permute 1), scaled by the multiplier; counted
+    once per operand, so a tuple op from XLA's collective combiner
+    counts as the collectives it combined.
 
 Caveat (documented in EXPERIMENTS.md): the CPU backend upcasts bf16 dot
 operands to f32 before compute and collectives, so byte counts here are a
@@ -77,23 +79,21 @@ def _type_bytes(type_str: str) -> int:
     return total
 
 
-def _max_shape_bytes(type_str: str) -> int:
-    """Largest single shape in a (possibly tuple) type.
+def _start_payload_bytes(type_str: str, num_operands: int) -> int:
+    """Payload of an async collective ``*-start`` op.
 
-    Async collective ``*-start`` ops return a tuple carrying the operand
-    alias, the result buffer, and (on some backends) u32 context scalars
-    — summing the tuple double-counts the payload, so the payload is the
-    largest member."""
-    best = 0
-    for dtype, dims in _shapes_in(type_str):
-        if dtype not in DTYPE_BYTES:
-            continue
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        best = max(best, n * DTYPE_BYTES[dtype])
-    return best
+    Its tuple type may carry, besides the results, the operand aliases
+    and (on some backends) u32 context scalars — summing the whole tuple
+    double-counts the payload.  Once the scalar contexts are dropped, the
+    results are the last ``num_operands`` arrays (all of them where no
+    alias is carried), and a combined op's payload is their sum."""
+    arrays = [
+        (dtype, dims) for dtype, dims in _shapes_in(type_str)
+        if dtype in DTYPE_BYTES and not (dtype == "u32" and dims == "")
+    ]
+    return sum(
+        _type_bytes(f"{dtype}[{dims}]") for dtype, dims in arrays[-num_operands:]
+    )
 
 
 def _dims(type_str: str) -> list[int]:
@@ -147,6 +147,10 @@ class CollectiveOp:
     result_bytes: int
     group_size: int
     multiplier: int = 1
+    #: Arrays the op reduces or moves: XLA's collective combiners fuse
+    #: several same-kind collectives into one tuple-shaped op, and each
+    #: operand is still one logical collective.
+    operands: int = 1
 
     @property
     def wire_bytes(self) -> float:
@@ -170,9 +174,11 @@ class ModuleAnalysis:
         return out
 
     def collective_counts(self) -> dict[str, int]:
+        """Logical collectives per op kind, so a count does not depend on
+        whether the compiler combined them (one per combined operand)."""
         out: dict[str, int] = {}
         for o in self.collectives:
-            out[o.op] = out.get(o.op, 0) + o.multiplier
+            out[o.op] = out.get(o.op, 0) + o.multiplier * o.operands
         return out
 
 
@@ -278,8 +284,9 @@ def analyze_module(text: str) -> ModuleAnalysis:
             # and skip the matching done (it would double-count).
             base_op = opcode[: -len("-start")] if opcode.endswith("-start") else opcode
             if base_op in _COLLECTIVES and not opcode.endswith("-done"):
+                n_ops = max(1, len(_operands(rest)))
                 rb = (
-                    _max_shape_bytes(type_str)
+                    _start_payload_bytes(type_str, n_ops)
                     if opcode.endswith("-start")
                     else _type_bytes(type_str)
                 )
@@ -288,6 +295,7 @@ def analyze_module(text: str) -> ModuleAnalysis:
                         CollectiveOp(
                             op=base_op, computation=comp, result_bytes=rb,
                             group_size=_group_size(line), multiplier=m_comp,
+                            operands=n_ops,
                         )
                     )
             if opcode in _TRAFFIC_OPS:

@@ -13,8 +13,9 @@ Per spec the linter runs (lowering only — nothing executes):
                 inverse-closure under faults, compressed H**B)
 - ``retrace``   cache-key completeness (field perturbation, value level)
 - ``wire``      lowered collective counts / payload widths vs the
-                declared eq.-15 budget (needs an M-device mesh; the CLI
-                fakes one on CPU, exactly like ``train_dssfn``)
+                declared eq.-15 budget (needs an M-device mesh; under
+                ``JAX_PLATFORMS=cpu`` the CLI fakes one, exactly like
+                ``train_dssfn``)
 - ``numerics``  StableHLO accumulation-dtype + guarded-cholesky lint of
                 the lowered hot program
 - ``source``    AST rules over ``src/repro`` (once, not per spec)

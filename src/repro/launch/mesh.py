@@ -14,15 +14,10 @@ import jax
 
 
 def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` only exists from jax 0.5; on older jaxlib (0.4.x, the
-    pinned CI version) every axis is implicitly Auto already.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -43,18 +38,27 @@ def make_worker_mesh(num_workers: int | None = None):
 
     Used by ``core.backend.MeshBackend``.  On CPU, fake devices must be
     requested via ``XLA_FLAGS=--xla_force_host_platform_device_count=M``
-    BEFORE jax initializes (the ``launch.train_dssfn`` CLI does this); on
-    TPU the slots are real chips and the ring-gossip mode maps each
-    degree-k hop onto an ICI collective_permute.
+    BEFORE jax initializes (the ``launch.train_dssfn`` CLI does this under
+    ``JAX_PLATFORMS=cpu``); on TPU the slots are real chips and the
+    ring-gossip mode maps each degree-k hop onto an ICI collective_permute.
     """
-    n = len(jax.devices())
+    devices = jax.devices()
+    n = len(devices)
     if num_workers is None:
         num_workers = n
     if num_workers > n:
+        platform = devices[0].platform
+        hint = (
+            "; on CPU set XLA_FLAGS=--xla_force_host_platform_device_count="
+            f"{num_workers} before jax initializes (the launchers do so "
+            "under JAX_PLATFORMS=cpu)"
+            if platform == "cpu"
+            else "; the mesh backend runs one worker per device — use "
+            "--backend simulated to vmap more workers onto fewer devices"
+        )
         raise ValueError(
-            f"requested {num_workers} workers but only {n} devices are "
-            "visible; on CPU set XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count={num_workers} before jax initializes"
+            f"requested {num_workers} workers but only {n} {platform} "
+            f"device(s) ({devices[0].device_kind}) are visible{hint}"
         )
     return make_mesh_compat((num_workers,), ("workers",))
 

@@ -263,7 +263,10 @@ def main(argv=None) -> dict:
 
     import numpy as np
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import MicroBatcher, ServeEngine, load_artifact
+
+    enable_compile_cache()
 
     artifact = load_artifact(args.artifact)
     if args.features is not None:

@@ -59,15 +59,18 @@ policy it swaps that policy's graph.  ``--partition iid|noniid[:alpha]``
 controls worker-shard label skew, so topology sweeps can run against
 non-IID shards (centralized equivalence is distribution-free).
 
-On CPU the mesh is faked with XLA host devices: the launcher sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=M`` BEFORE jax
-initializes (which is why every jax import in this module is deferred).
-On TPU the worker slots are real chips and gossip-family policies map
-each degree-k hop onto an ICI collective_permute.
+Under ``JAX_PLATFORMS=cpu`` the mesh is faked with XLA host devices:
+the launcher sets ``XLA_FLAGS=--xla_force_host_platform_device_count=M``
+BEFORE jax initializes (which is why every jax import in this module is
+deferred).  On TPU the worker slots are real chips and gossip-family
+policies map each degree-k hop onto an ICI collective_permute; the mesh
+backend needs one chip per worker, so a one-chip host runs the paper's
+deployment as ``--backend simulated --workers 20 --no-host-mesh``.
 
 Usage::
 
-    python -m repro.launch.train_dssfn --workers 8 --backend both
+    JAX_PLATFORMS=cpu python -m repro.launch.train_dssfn --workers 8 \
+        --backend both
     python -m repro.launch.train_dssfn --workers 8 --consensus gossip \
         --degree 2 --rounds 10
     python -m repro.launch.train_dssfn --workers 8 --backend mesh \
@@ -78,6 +81,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import sys
 import time
 
 
@@ -232,21 +236,42 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 
 def ensure_devices(num_workers: int, *, allow_fake: bool = True) -> None:
-    """Fake an M-device CPU host mesh.
+    """Fake an M-device CPU host mesh when the run is pinned to the CPU.
 
-    XLA reads the flag at first backend initialization, so this works as
-    long as no ``jax.devices()``/computation has run yet — hence the
-    deferred jax imports throughout this module.  No-op when the operator
-    pinned a real accelerator platform or already set the flag.
+    Only under ``JAX_PLATFORMS=cpu``: anywhere else the flag would let a
+    host whose accelerator failed to initialize fall back to fake CPU
+    devices and train on them quietly.  XLA reads the flag at first
+    backend initialization, so this works as long as no
+    ``jax.devices()``/computation has run yet — hence the deferred jax
+    imports throughout this module.  No-op when the flag is already set.
     """
-    if not allow_fake:
-        return
-    if os.environ.get("JAX_PLATFORMS", "").startswith(("tpu", "gpu")):
+    if not allow_fake or os.environ.get("JAX_PLATFORMS") != "cpu":
         return
     flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={num_workers}".strip()
+        )
+
+
+def report_devices() -> None:
+    """Print the devices the run will use; say so in words when JAX fell
+    back to the CPU without being asked to."""
+    import jax
+
+    devices = jax.devices()
+    print(
+        f"devices: {len(devices)} ({devices[0].platform}, "
+        f"{devices[0].device_kind})",
+        flush=True,
+    )
+    if devices[0].platform == "cpu" and not os.environ.get("JAX_PLATFORMS"):
+        print(
+            "warning: JAX found no accelerator and runs on the CPU; set "
+            "JAX_PLATFORMS=cpu to run there on purpose (with a fake host "
+            "mesh for --backend mesh)",
+            file=sys.stderr,
+            flush=True,
         )
 
 
@@ -350,8 +375,10 @@ def main(argv=None) -> dict:
 
     from repro.core import ssfn
     from repro.data import make_classification, partition_by_spec
+    from repro.launch.compile_cache import enable_compile_cache
 
-    print(f"devices: {len(jax.devices())} ({jax.default_backend()})", flush=True)
+    enable_compile_cache()
+    report_devices()
 
     data = make_classification(
         jax.random.PRNGKey(args.seed),

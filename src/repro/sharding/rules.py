@@ -46,28 +46,13 @@ class AxisRules:
 
 
 def shard_map_compat(fn, *, mesh, in_specs, out_specs, axis_names=None):
-    """``shard_map`` across jax versions, replication checking disabled.
-
-    jax >= 0.6 exposes ``jax.shard_map`` (with ``check_vma`` and optional
-    ``axis_names``); the pinned 0.4.x CI jaxlib only has
-    ``jax.experimental.shard_map.shard_map`` (with ``check_rep`` and no
-    axis subsetting).  ``axis_names`` is honoured where supported and may
-    be dropped on the fallback — call sites here always map over every
-    mesh axis, where the two behaviours coincide.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kwargs: dict = dict(
-            mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return sm(fn, **kwargs)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` with replication checking disabled."""
+    kwargs: dict = dict(
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
+    if axis_names is not None:
+        kwargs["axis_names"] = axis_names
+    return jax.shard_map(fn, **kwargs)
 
 
 _state = threading.local()

@@ -158,11 +158,67 @@ ENTRY %main (p0: f32[4,8]) -> f32[4,8] {
     assert a.collective_counts() == {"all-reduce": 2, "collective-permute": 1}
     payload = 4 * 8 * 4
     # Start tuples carry operand alias + u32 context scalars: the payload
-    # is the largest member, never the tuple sum.
+    # is the result, never the tuple sum.
     assert [o.result_bytes for o in a.collectives] == [payload] * 3
     by_type = a.collective_by_type()
     assert by_type["collective-permute"] == payload
     assert by_type["all-reduce"] == 2 * (2.0 * payload * 3 / 4)
+
+
+def test_hlo_analysis_counts_combined_collectives_per_operand():
+    """XLA's all-reduce combiner fuses same-kind reductions into one
+    tuple op; each combined operand is still one logical collective, so
+    counts do not change with the combiner (inside a K-trip loop too)."""
+    from repro.launch.hlo_analysis import analyze_module
+
+    text = """\
+HloModule combined_probe
+
+%body (p: (s32[], f32[], f32[], f32[4])) -> (s32[], f32[], f32[], f32[4]) {
+  %p = (s32[], f32[], f32[], f32[4]) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %a = f32[] get-tuple-element(%p), index=1
+  %b = f32[] get-tuple-element(%p), index=2
+  %c = f32[4] get-tuple-element(%p), index=3
+  %ar = (f32[], f32[], f32[4]) all-reduce(f32[] %a, f32[] %b, f32[4] %c), replica_groups={{0,1,2,3}}, to_apply=%add
+  %mx = f32[] all-reduce(f32[] %a), replica_groups={{0,1,2,3}}, to_apply=%max
+  ROOT %t = (s32[], f32[], f32[], f32[4]) tuple(%i, %a, %b, %c)
+}
+
+%cond (p: (s32[], f32[], f32[], f32[4])) -> pred[] {
+  %p = (s32[], f32[], f32[], f32[4]) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %k = s32[] constant(10)
+  ROOT %lt = pred[] compare(%i, %k), direction=LT
+}
+
+ENTRY %main (x: (s32[], f32[], f32[], f32[4])) -> (s32[], f32[], f32[], f32[4]) {
+  %x = (s32[], f32[], f32[], f32[4]) parameter(0)
+  ROOT %w = (s32[], f32[], f32[], f32[4]) while(%x), condition=%cond, body=%body
+}
+"""
+    counts = analyze_module(text).collective_counts()
+    assert counts == {"all-reduce": 4 * 10}, counts
+
+    # Combined async forms: the bytes follow the count, one result per
+    # operand, without the operand aliases or the u32 contexts.
+    text = """\
+HloModule combined_async_probe
+
+ENTRY %main (a: f32[], b: f32[], c: f32[4]) -> f32[4] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  %c = f32[4]{0} parameter(2)
+  %ars = (f32[], f32[], f32[4]) all-reduce-start(f32[] %a, f32[] %b, f32[4]{0} %c), replica_groups={{0,1,2,3}}, to_apply=%add
+  %ard = (f32[], f32[], f32[4]) all-reduce-done(%ars)
+  %ags = ((f32[4], f32[1]), (f32[16], f32[4]), u32[], u32[]) all-gather-start(f32[4]{0} %c, f32[1]{0} %b), replica_groups={{0,1,2,3}}, dimensions={0}
+  %agd = (f32[16], f32[4]) all-gather-done(%ags)
+  ROOT %r = f32[4]{0} get-tuple-element(%ard), index=2
+}
+"""
+    a = analyze_module(text)
+    assert a.collective_counts() == {"all-reduce": 3, "all-gather": 2}
+    assert [o.result_bytes for o in a.collectives] == [4 + 4 + 16, 64 + 16]
 
 
 # -------------------------------------------------------------- sharding
@@ -185,3 +241,83 @@ def test_param_specs_drop_nondivisible():
     specs = param_spec_tree(shapes, rules, mesh)
     # (1,1) mesh: everything divides; spec carries the logical axes
     assert specs["layers"]["attn"]["wq"] is not None
+
+
+# ------------------------------------------------- launch: devices, cache
+
+def test_worker_mesh_error_names_platform_and_cpu_remedy():
+    import pytest
+
+    from repro.launch.mesh import make_worker_mesh
+
+    want = len(jax.devices()) + 1
+    with pytest.raises(ValueError) as err:
+        make_worker_mesh(want)
+    msg = str(err.value)
+    assert f"{len(jax.devices())} {jax.devices()[0].platform} device" in msg
+    if jax.devices()[0].platform == "cpu":
+        assert f"xla_force_host_platform_device_count={want}" in msg
+
+
+def test_ensure_devices_fakes_cpu_mesh_only_when_pinned(monkeypatch):
+    from repro.launch.train_dssfn import ensure_devices
+
+    monkeypatch.delenv("XLA_FLAGS", raising=False)
+    for platforms in (None, "tpu", "cuda"):
+        if platforms is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        ensure_devices(8)
+        assert "XLA_FLAGS" not in os.environ, platforms
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    ensure_devices(8, allow_fake=False)
+    assert "XLA_FLAGS" not in os.environ
+    ensure_devices(8)
+    assert os.environ["XLA_FLAGS"] == "--xla_force_host_platform_device_count=8"
+
+
+def test_compile_cache_dir_fixed_in_checkout_unless_env(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.compile_cache_dir() == os.path.join(root, ".jax_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # left to JAX
+
+
+def test_compile_cache_lands_where_configured(tmp_path):
+    """In a fresh process: the env var's directory receives the entries;
+    without it, JAX is pointed at the checkout's ``.jax_cache``."""
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = (
+        "import jax\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "import os\n"
+        "if os.environ.get('JAX_COMPILATION_CACHE_DIR'):\n"
+        "    jax.jit(lambda x: x * 2.0)(1.0).block_until_ready()\n"
+    )
+    base = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    base.update(PYTHONPATH=src, JAX_PLATFORMS="cpu",
+                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    out = subprocess.run([sys.executable, "-c", code], env=base,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    chosen, configured = out.stdout.split()[-2:]
+    assert chosen == configured
+    assert chosen.endswith(os.path.join("", ".jax_cache"))
+    env = dict(base, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split()[-2:] == [str(tmp_path / "cache")] * 2
+    assert os.listdir(tmp_path / "cache")

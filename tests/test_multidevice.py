@@ -319,6 +319,8 @@ def test_compressed_gossip_and_hot_path_on_8_devices():
     # and not a single reduction collective.
     assert hot == expect_hot(pol), (hot, expect_hot(pol))
     # trace_every=1 adds the psum obj + psum primal + cerr pmean/pmax.
+    # XLA's all-reduce combiner fuses three of them into one tuple op;
+    # the analysis counts each combined operand, so this holds either way.
     assert traced.get("all-reduce", 0) == 4 * K, traced
 
     ex_hot = probe(ExactMean(), 0)["collective_counts"]

@@ -218,9 +218,10 @@ def test_lowering_stats_reports_compiled_program():
     )
     assert set(stats) == {
         "collective_counts", "collective_wire_bytes", "collective_by_type",
-        "flops",
+        "flops", "memory_bytes",
     }
     assert stats["flops"] > 0
+    assert stats["memory_bytes"] > 0
     # Shares the executable cache with run() — and with lowering_texts,
     # whose StableHLO is what repro.analysis.numerics lints.
     assert ("stats-probe", 2, 1, (), True, None) in backend._exec_cache
