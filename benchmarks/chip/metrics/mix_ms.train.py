@@ -1,0 +1,9 @@
+"""Device time under the ``admm.mix`` scope (the policy's consensus
+exchange, K times) per layer program, as a mean over layer programs and
+chips (trace: each op's ``tf_op`` scope path)."""
+from benchmarks.chip import program_trace
+
+
+def read(r):
+    names = program_trace.names()
+    return None if names is None else program_trace.scope_ms_per_program(r, names.MIX)

@@ -31,6 +31,7 @@ from typing import TYPE_CHECKING, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import profiling
 from repro.core import consensus as consensus_lib
 from repro.core.policy import ConsensusPolicy
 
@@ -91,23 +92,25 @@ def guarded_cholesky(
     the layerwise divergence guard owns that failure.
     """
     n = g.shape[-1]
-    eye = jnp.eye(n, dtype=g.dtype)
-    scale = jnp.maximum(
-        jnp.mean(jnp.abs(jnp.diagonal(g))), jnp.asarray(1.0, g.dtype)
-    )
 
     def cond(state):
         k, chol = state
         return (k < max_tries) & ~jnp.all(jnp.isfinite(chol))
 
-    def body(state):
-        k, _ = state
-        eps = scale * base_jitter * jnp.asarray(10.0, g.dtype) ** k.astype(g.dtype)
-        return k + 1, jnp.linalg.cholesky(g + eps * eye)
+    with profiling.scope(profiling.CHOLESKY):
+        eye = jnp.eye(n, dtype=g.dtype)
+        scale = jnp.maximum(
+            jnp.mean(jnp.abs(jnp.diagonal(g))), jnp.asarray(1.0, g.dtype)
+        )
 
-    k, chol = jax.lax.while_loop(
-        cond, body, (jnp.zeros((), jnp.int32), jnp.linalg.cholesky(g))
-    )
+        def body(state):
+            k, _ = state
+            eps = scale * base_jitter * jnp.asarray(10.0, g.dtype) ** k.astype(g.dtype)
+            return k + 1, jnp.linalg.cholesky(g + eps * eye)
+
+        k, chol = jax.lax.while_loop(
+            cond, body, (jnp.zeros((), jnp.int32), jnp.linalg.cholesky(g))
+        )
     return chol, k
 
 
@@ -259,14 +262,16 @@ def _worker_stats_local(y_m: Array, t_m: Array, mu: float, use_kernels: bool):
     worker axis, same Pallas ``gram`` kernel routing on aligned shapes.
     """
     n, j = y_m.shape
-    if use_kernels and n % 128 == 0 and j % 128 == 0:
-        from repro.kernels.gram import gram as gram_kernel
+    with profiling.scope(profiling.GRAM):
+        if use_kernels and n % 128 == 0 and j % 128 == 0:
+            from repro.kernels.gram import gram as gram_kernel
 
-        gram = gram_kernel(y_m, mu=mu).astype(y_m.dtype)
-    else:
-        gram = y_m @ y_m.T + (1.0 / mu) * jnp.eye(n, dtype=y_m.dtype)
+            gram = gram_kernel(y_m, mu=mu).astype(y_m.dtype)
+        else:
+            gram = y_m @ y_m.T + (1.0 / mu) * jnp.eye(n, dtype=y_m.dtype)
     chol, jitter = guarded_cholesky(gram)
-    a = t_m @ y_m.T
+    with profiling.scope(profiling.GRAM):
+        a = t_m @ y_m.T
     return a, chol, jitter
 
 
@@ -351,25 +356,35 @@ def worker_admm_iterations(
     q, n = a.shape
     dtype = a.dtype
 
+    def solve(z, lam):
+        """O_m = (A_m + (Z - Lam_m)/mu) G_m^{-1} via the cached factor."""
+        with profiling.scope(profiling.SOLVE):
+            rhs = a + (z - lam) / mu
+            return jax.scipy.linalg.cho_solve((chol, True), rhs.T).T
+
+    def update(o, lam, avg):
+        """The projection onto the eps-ball and the dual step."""
+        with profiling.scope(profiling.UPDATE):
+            z_new = project_frobenius(avg, eps_radius)
+            return z_new, lam + o - z_new
+
     def iterate(carry):
         """One eq.-11 iteration; also returns what tracing needs."""
         (_, z, lam), pstate = carry
-        rhs = a + (z - lam) / mu
-        o = jax.scipy.linalg.cho_solve((chol, True), rhs.T).T
-        avg, pstate = policy.mix(o + lam, pstate, ctx)
-        z_new = project_frobenius(avg, eps_radius)
-        lam_new = lam + o - z_new
+        o = solve(z, lam)
+        with profiling.scope(profiling.MIX):
+            avg, pstate = policy.mix(o + lam, pstate, ctx)
+        z_new, lam_new = update(o, lam, avg)
         return ((o, z_new, lam_new), pstate), (avg, z)
 
     def local_iterate(carry):
         """A skipped round: the same eq.-11 update against the worker's
         OWN estimate (avg = o + lam, no wire, no policy-state advance)."""
         (_, z, lam), pstate = carry
-        rhs = a + (z - lam) / mu
-        o = jax.scipy.linalg.cho_solve((chol, True), rhs.T).T
-        avg = o + lam
-        z_new = project_frobenius(avg, eps_radius)
-        lam_new = lam + o - z_new
+        o = solve(z, lam)
+        with profiling.scope(profiling.UPDATE):
+            avg = o + lam
+        z_new, lam_new = update(o, lam, avg)
         return ((o, z_new, lam_new), pstate), (avg, z)
 
     def trace(carry, avg, z_prev):
@@ -403,66 +418,67 @@ def worker_admm_iterations(
         carry, (avg, z_prev) = local_iterate(carry)
         return carry, trace(carry, avg, z_prev)
 
-    zeros = jnp.zeros((q, n), dtype)
-    init = ((zeros, z_init, zeros), policy.init_state(zeros, ctx))
-    if interval > 1:
-        # Communication-interval chunks: N-1 local rounds, one on the
-        # wire.  The whole fault/membership story rides inside the
-        # communicating iterate's policy.mix — still one executable.
-        if trace_every == 0:
-            def comm_chunk(carry, _):
-                carry, _ = jax.lax.scan(
-                    step_untraced_local, carry, None, length=interval - 1
-                )
-                carry, _ = iterate(carry)
-                return carry, None
+    with profiling.scope(profiling.ADMM):
+        zeros = jnp.zeros((q, n), dtype)
+        init = ((zeros, z_init, zeros), policy.init_state(zeros, ctx))
+        if interval > 1:
+            # Communication-interval chunks: N-1 local rounds, one on the
+            # wire.  The whole fault/membership story rides inside the
+            # communicating iterate's policy.mix — still one executable.
+            if trace_every == 0:
+                def comm_chunk(carry, _):
+                    carry, _ = jax.lax.scan(
+                        step_untraced_local, carry, None, length=interval - 1
+                    )
+                    carry, _ = iterate(carry)
+                    return carry, None
 
-            (state, _), _ = jax.lax.scan(
+                (state, _), _ = jax.lax.scan(
+                    comm_chunk, init, None, length=num_iters // interval
+                )
+                return state, None
+
+            def comm_chunk(carry, _):
+                carry, local_traces = jax.lax.scan(
+                    step_traced_local, carry, None, length=interval - 1
+                )
+                carry, comm_trace = step_traced(carry, None)
+                chunk_traces = jax.tree.map(
+                    lambda ls, c: jnp.concatenate([ls, c[None]]),
+                    local_traces, comm_trace,
+                )
+                return carry, chunk_traces
+
+            (state, _), traces = jax.lax.scan(
                 comm_chunk, init, None, length=num_iters // interval
             )
+            # (K/N, N) chunked traces -> flat (K,) per-iteration traces.
+            traces = jax.tree.map(
+                lambda v: v.reshape((num_iters,) + v.shape[2:]), traces
+            )
+            return state, traces
+        if trace_every == 0:
+            (state, _), _ = jax.lax.scan(
+                step_untraced, init, None, length=num_iters
+            )
             return state, None
+        if trace_every == 1:
+            (state, _), traces = jax.lax.scan(
+                step_traced, init, None, length=num_iters
+            )
+            return state, traces
 
-        def comm_chunk(carry, _):
-            carry, local_traces = jax.lax.scan(
-                step_traced_local, carry, None, length=interval - 1
+        def chunk(carry, _):
+            # trace_every - 1 collective-free iterations, then one traced.
+            carry, _ = jax.lax.scan(
+                step_untraced, carry, None, length=trace_every - 1
             )
-            carry, comm_trace = step_traced(carry, None)
-            chunk_traces = jax.tree.map(
-                lambda ls, c: jnp.concatenate([ls, c[None]]),
-                local_traces, comm_trace,
-            )
-            return carry, chunk_traces
+            return step_traced(carry, None)
 
         (state, _), traces = jax.lax.scan(
-            comm_chunk, init, None, length=num_iters // interval
-        )
-        # (K/N, N) chunked traces -> flat (K,) per-iteration traces.
-        traces = jax.tree.map(
-            lambda v: v.reshape((num_iters,) + v.shape[2:]), traces
+            chunk, init, None, length=num_iters // trace_every
         )
         return state, traces
-    if trace_every == 0:
-        (state, _), _ = jax.lax.scan(
-            step_untraced, init, None, length=num_iters
-        )
-        return state, None
-    if trace_every == 1:
-        (state, _), traces = jax.lax.scan(
-            step_traced, init, None, length=num_iters
-        )
-        return state, traces
-
-    def chunk(carry, _):
-        # trace_every - 1 collective-free iterations, then one traced.
-        carry, _ = jax.lax.scan(
-            step_untraced, carry, None, length=trace_every - 1
-        )
-        return step_traced(carry, None)
-
-    (state, _), traces = jax.lax.scan(
-        chunk, init, None, length=num_iters // trace_every
-    )
-    return state, traces
 
 
 def _admm_backend_path(

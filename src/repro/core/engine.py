@@ -37,6 +37,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import profiling
 from repro.core import admm as admm_lib
 from repro.core.backend import ConsensusBackend
 from repro.core.policy import ConsensusPolicy
@@ -69,15 +70,19 @@ def _propagate_and_stats(w, y_m, t_m, mu: float, use_kernels: bool):
     if use_kernels and _aligned(n_out, n_in, j):
         from repro.kernels.propagate_gram import propagate_gram
 
-        y_new, gram = propagate_gram(w, y_m, mu=mu)
-        y_new = y_new.astype(y_m.dtype)
-        gram = gram.astype(y_m.dtype)
+        # The fused kernel's propagation counts as Gram work.
+        with profiling.scope(profiling.GRAM):
+            y_new, gram = propagate_gram(w, y_m, mu=mu)
+            y_new = y_new.astype(y_m.dtype)
+            gram = gram.astype(y_m.dtype)
         chol, jitter = admm_lib.guarded_cholesky(gram)
-        a = t_m @ y_new.T
+        with profiling.scope(profiling.GRAM):
+            a = t_m @ y_new.T
         return y_new, a, chol, jitter
     # Unfused: plain propagation, then the same stats construction (and
     # gram-kernel routing) the direct ADMM path uses.
-    y_new = jax.nn.relu(w @ y_m)
+    with profiling.scope(profiling.PROPAGATE):
+        y_new = jax.nn.relu(w @ y_m)
     a, chol, jitter = admm_lib._worker_stats_local(y_new, t_m, mu, use_kernels)
     return y_new, a, chol, jitter
 

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import profiling
 from repro.core import admm as admm_lib
 from repro.core import engine as engine_lib
 from repro.core import ssfn as ssfn_lib
@@ -404,134 +405,146 @@ def train_decentralized_ssfn(
 
     layer = layer_start
     while layer <= cfg.num_layers:
-        step = engine_lib.fused_layer_step(
-            engine_backend,
-            y_workers,
-            t_workers,
-            w_next,
-            mu=_mu_for_layer(cfg, layer),
-            eps_radius=cfg.eps_radius,
-            num_iters=cfg.admm_iters,
-            use_kernels=cfg.use_kernels,
-            policy=policy,
-            trace_every=trace_every,
-            # From layer 2 on, the stacked Y is a fresh relu(W@Y) buffer
-            # the engine owns — safe to hand to XLA.  Layers 0 and 1 must
-            # NOT donate: layer 0's input is the caller's x_workers, and
-            # layer 0's pass-through output may alias it.
-            donate_y=layer > 1,
-        )
+        with profiling.span(profiling.LAYER, layer=layer):
+            with profiling.span(profiling.DISPATCH):
+                step = engine_lib.fused_layer_step(
+                    engine_backend,
+                    y_workers,
+                    t_workers,
+                    w_next,
+                    mu=_mu_for_layer(cfg, layer),
+                    eps_radius=cfg.eps_radius,
+                    num_iters=cfg.admm_iters,
+                    use_kernels=cfg.use_kernels,
+                    policy=policy,
+                    trace_every=trace_every,
+                    # From layer 2 on, the stacked Y is a fresh relu(W@Y) buffer
+                    # the engine owns — safe to hand to XLA.  Layers 0 and 1 must
+                    # NOT donate: layer 0's input is the caller's x_workers, and
+                    # layer 0's pass-through output may alias it.
+                    donate_y=layer > 1,
+                )
 
-        if guard_divergence and _step_diverged(step, prev_cost):
-            if rollbacks >= max_rollbacks:
-                raise RuntimeError(
-                    f"layer {layer} diverged and the rollback budget "
-                    f"(max_rollbacks={max_rollbacks}) is spent"
+            diverged = False
+            if guard_divergence:
+                with profiling.span(profiling.HOST_SYNC):
+                    diverged = _step_diverged(step, prev_cost)
+            if diverged:
+                if rollbacks >= max_rollbacks:
+                    raise RuntimeError(
+                        f"layer {layer} diverged and the rollback budget "
+                        f"(max_rollbacks={max_rollbacks}) is spent"
+                    )
+                rollbacks += 1
+                ckpt = (
+                    latest_checkpoint(checkpoint_dir)
+                    if checkpoint_dir is not None else None
                 )
-            rollbacks += 1
-            ckpt = (
-                latest_checkpoint(checkpoint_dir)
-                if checkpoint_dir is not None else None
-            )
-            if ckpt is not None:
-                restored = _load_checkpoint(ckpt)
-                layer = restored["layer_next"]
-                key = restored["key"]
-                o_list = list(restored["o_list"])
-                dev_traces = list(restored["traces"])
-                jitter_list = list(restored["jitter_list"] or [])
-                comm = restored["comm"]
-                prev_cost = restored["prev_cost"]
-                y_workers = engine_backend.shard_workers(
-                    restored["y_workers"]
+                if ckpt is not None:
+                    restored = _load_checkpoint(ckpt)
+                    layer = restored["layer_next"]
+                    key = restored["key"]
+                    o_list = list(restored["o_list"])
+                    dev_traces = list(restored["traces"])
+                    jitter_list = list(restored["jitter_list"] or [])
+                    comm = restored["comm"]
+                    prev_cost = restored["prev_cost"]
+                    y_workers = engine_backend.shard_workers(
+                        restored["y_workers"]
+                    )
+                    if restored["r_list"] is not None:
+                        r_list = list(restored["r_list"])
+                else:
+                    (layer, key, o_list, dev_traces, jitter_list, comm,
+                     prev_cost, y_workers, w_next, r_list) = entry_state
+                    o_list = list(o_list)
+                    dev_traces = list(dev_traces)
+                    jitter_list = list(jitter_list)
+                    r_list = list(r_list)
+                warnings.warn(
+                    f"layer solve diverged; rolling back to layer {layer} "
+                    f"with a perturbed key (rollback {rollbacks}/"
+                    f"{max_rollbacks})",
+                    RuntimeWarning,
+                    stacklevel=2,
                 )
-                if restored["r_list"] is not None:
-                    r_list = list(restored["r_list"])
+                # Perturb the key and re-draw every random matrix the
+                # restart point has not consumed.  r[layer-1] only feeds the
+                # NEXT propagation (it rebuilds w_next below), so it is
+                # still free to change; r[0..layer-2] shaped the restored
+                # features and must stay verbatim.
+                key = jax.random.fold_in(key, 7 + rollbacks)
+                fresh = ssfn_lib.init_random_matrices(key, cfg)
+                first_free = max(layer - 1, 0)
+                r_list[first_free:] = list(fresh[first_free:])
+                if layer == 0:
+                    w_next = None
+                elif layer <= cfg.num_layers:
+                    with profiling.span(profiling.BUILD_WEIGHT):
+                        w_next = ssfn_lib.build_weight(
+                            o_list[-1], r_list[layer - 1], q
+                        )
+                continue
+
+            y_workers = step.y_workers
+            o_list.append(step.o_star)
+            if step.trace is not None:
+                dev_traces.append(step.trace)
+            if step.jitter is not None:
+                with profiling.span(profiling.HOST_SYNC):
+                    jitter_list.append(np.asarray(jax.device_get(step.jitter)))
+            # Communication accounting, eq. 15: Q * n_{l-1} scalars per
+            # exchange, B exchanges per consensus, K communicating consensus
+            # rounds per layer — the policy itself knows its exchange count
+            # and how many of the K iterations actually hit the wire.
+            if explicit:
+                comm += policy.comm_scalars(
+                    scalars=q * y_workers.shape[1],
+                    num_consensus=cfg.admm_iters,
+                    num_workers=num_workers,
+                )
             else:
-                (layer, key, o_list, dev_traces, jitter_list, comm,
-                 prev_cost, y_workers, w_next, r_list) = entry_state
-                o_list = list(o_list)
-                dev_traces = list(dev_traces)
-                jitter_list = list(jitter_list)
-                r_list = list(r_list)
-            warnings.warn(
-                f"layer solve diverged; rolling back to layer {layer} "
-                f"with a perturbed key (rollback {rollbacks}/"
-                f"{max_rollbacks})",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            # Perturb the key and re-draw every random matrix the
-            # restart point has not consumed.  r[layer-1] only feeds the
-            # NEXT propagation (it rebuilds w_next below), so it is
-            # still free to change; r[0..layer-2] shaped the restored
-            # features and must stay verbatim.
-            key = jax.random.fold_in(key, 7 + rollbacks)
-            fresh = ssfn_lib.init_random_matrices(key, cfg)
-            first_free = max(layer - 1, 0)
-            r_list[first_free:] = list(fresh[first_free:])
-            if layer == 0:
-                w_next = None
-            elif layer <= cfg.num_layers:
-                w_next = ssfn_lib.build_weight(
-                    o_list[-1], r_list[layer - 1], q
-                )
-            continue
+                comm += q * y_workers.shape[1] * gossip_rounds * cfg.admm_iters
 
-        y_workers = step.y_workers
-        o_list.append(step.o_star)
-        if step.trace is not None:
-            dev_traces.append(step.trace)
-        if step.jitter is not None:
-            jitter_list.append(np.asarray(jax.device_get(step.jitter)))
-        # Communication accounting, eq. 15: Q * n_{l-1} scalars per
-        # exchange, B exchanges per consensus, K communicating consensus
-        # rounds per layer — the policy itself knows its exchange count
-        # and how many of the K iterations actually hit the wire.
-        if explicit:
-            comm += policy.comm_scalars(
-                scalars=q * y_workers.shape[1],
-                num_consensus=cfg.admm_iters,
-                num_workers=num_workers,
-            )
-        else:
-            comm += q * y_workers.shape[1] * gossip_rounds * cfg.admm_iters
-
-        stopping = stop_after_layer is not None and layer >= stop_after_layer
-        if checkpoint_dir is not None and (
-            stopping or (layer + 1) % checkpoint_every == 0
-        ):
-            _save_checkpoint(
-                checkpoint_dir, layer_next=layer + 1, key=key,
-                y_workers=np.asarray(jax.device_get(y_workers)),
-                o_list=o_list, step=step, dev_traces=dev_traces,
-                comm=comm, prev_cost=prev_cost,
-                active_mask=_active_mask(policy, num_workers),
-                r_list=r_list, jitter_list=jitter_list,
-            )
-        if stopping:
-            break
-
-        # Self-size estimation: every worker sees the same consensus
-        # objective, so this stop decision is itself consensual.  This is
-        # the loop's ONLY per-layer host sync — one scalar fetch; without
-        # size estimation the whole train runs sync-free.
-        if size_estimation_tol is not None:
-            cur = float(step.trace.objective[-1])
-            if (
-                prev_cost is not None
-                and prev_cost - cur < size_estimation_tol * max(prev_cost, 1e-12)
+            stopping = stop_after_layer is not None and layer >= stop_after_layer
+            if checkpoint_dir is not None and (
+                stopping or (layer + 1) % checkpoint_every == 0
             ):
+                with profiling.span(profiling.HOST_SYNC):
+                    _save_checkpoint(
+                        checkpoint_dir, layer_next=layer + 1, key=key,
+                        y_workers=np.asarray(jax.device_get(y_workers)),
+                        o_list=o_list, step=step, dev_traces=dev_traces,
+                        comm=comm, prev_cost=prev_cost,
+                        active_mask=_active_mask(policy, num_workers),
+                        r_list=r_list, jitter_list=jitter_list,
+                    )
+            if stopping:
                 break
-            prev_cost = cur
-        elif guard_divergence and step.trace is not None:
-            # Track the layer cost so the guard's blow-up check has a
-            # reference even without size estimation.
-            prev_cost = float(step.trace.objective[-1])
 
-        if layer < cfg.num_layers:
-            w_next = ssfn_lib.build_weight(step.o_star, r_list[layer], q)
-        layer += 1
+            # Self-size estimation: every worker sees the same consensus
+            # objective, so this stop decision is itself consensual.  It
+            # costs one scalar fetch per layer; without it (and without the
+            # guard) the jitter fetch above is the loop's only host sync.
+            if size_estimation_tol is not None:
+                with profiling.span(profiling.HOST_SYNC):
+                    cur = float(step.trace.objective[-1])
+                if (
+                    prev_cost is not None
+                    and prev_cost - cur < size_estimation_tol * max(prev_cost, 1e-12)
+                ):
+                    break
+                prev_cost = cur
+            elif guard_divergence and step.trace is not None:
+                # Track the layer cost so the guard's blow-up check has a
+                # reference even without size estimation.
+                with profiling.span(profiling.HOST_SYNC):
+                    prev_cost = float(step.trace.objective[-1])
+
+            if layer < cfg.num_layers:
+                with profiling.span(profiling.BUILD_WEIGHT):
+                    w_next = ssfn_lib.build_weight(step.o_star, r_list[layer], q)
+            layer += 1
 
     # One bulk fetch of every per-layer trace after the loop.  The
     # collective-free hot path (trace_every=0) has none: the log carries

@@ -77,7 +77,7 @@ class PendingResult:
 
     __slots__ = (
         "num_samples", "submitted_at", "completed_at", "deadline",
-        "status", "error", "_value",
+        "status", "error", "request_id", "_value",
     )
 
     def __init__(self, num_samples: int, *, now: float | None = None):
@@ -90,6 +90,9 @@ class PendingResult:
         self.status = PENDING
         #: Error payload for the failed/rejected/expired states.
         self.error: str | None = None
+        #: The owning runtime's admission number (None under the bare
+        #: batcher); its profiler spans carry it.
+        self.request_id: int | None = None
         self._value = None
 
     def done(self) -> bool:

@@ -49,6 +49,7 @@ import time
 import jax
 import numpy as np
 
+from repro import profiling
 from repro.serve.batcher import (
     PendingResult,
     pack_fifo,
@@ -174,6 +175,9 @@ class ServeRuntime:
         self.chaos = chaos
 
         self._lock = threading.RLock()
+        #: The next admission number: taken under the lock, so the FIFO
+        #: queue holds requests in id order.
+        self._next_request_id = 0
         self._queue: list[tuple[np.ndarray, PendingResult]] = []
         self._pending_samples = 0
         self._state = STARTING
@@ -348,56 +352,68 @@ class ServeRuntime:
         blocks on the engine."""
         now = self.clock.now()
         handle = PendingResult(0, now=now)
-        with self._lock:
-            self.stats["submitted"] += 1
-            if self._state not in (READY,):
-                # DEGRADED still admits (it reports through .state, the
-                # stored lifecycle stays READY); anything else sheds.
-                self._reject_locked(
-                    handle, "state",
-                    f"runtime is {self.state}, not accepting requests",
-                    now,
-                )
-                return handle
+        with profiling.span(profiling.SUBMIT) as submit_span:
+            with profiling.span(profiling.LOCK_WAIT) as wait_span:
+                self._lock.acquire()
+                handle.request_id = self._next_request_id
+                self._next_request_id += 1
+                wait_span.set_metadata(request=handle.request_id)
+            submit_span.set_metadata(request=handle.request_id)
             try:
-                x = self._validate_request(x)
-            except ValueError as e:
-                self._reject_locked(handle, "poison", str(e), now)
-                return handle
-            j = x.shape[1]
-            handle.num_samples = j
-            if deadline_s is None:
-                deadline_s = self.default_deadline_s
-            if deadline_s is not None:
-                if deadline_s <= 0:
-                    handle._expire(
-                        f"deadline {deadline_s * 1e3:.3f} ms expired at "
-                        "admission", now=now,
-                    )
-                    self.stats["expired"] += 1
-                    return handle
-                handle.deadline = now + deadline_s
-            if (
-                len(self._queue) + 1 > self.max_pending_requests
-                or self._pending_samples + j > self.max_pending_samples
-            ):
-                self._reject_locked(
-                    handle, "overload",
-                    f"overloaded: {len(self._queue)} requests / "
-                    f"{self._pending_samples} samples pending (limits "
-                    f"{self.max_pending_requests} / "
-                    f"{self.max_pending_samples})",
-                    now,
-                )
-                return handle
-            self._queue.append((x, handle))
-            self._pending_samples += j
-            self.stats["max_queue_depth"] = max(
-                self.stats["max_queue_depth"], self._pending_samples
-            )
-            if self._pending_samples >= self.max_batch:
-                self._flush_locked()
+                self._admit_locked(handle, x, deadline_s, now)
+            finally:
+                self._lock.release()
         return handle
+
+    def _admit_locked(self, handle: PendingResult, x, deadline_s, now) -> None:
+        self.stats["submitted"] += 1
+        if self._state not in (READY,):
+            # DEGRADED still admits (it reports through .state, the
+            # stored lifecycle stays READY); anything else sheds.
+            self._reject_locked(
+                handle, "state",
+                f"runtime is {self.state}, not accepting requests",
+                now,
+            )
+            return
+        try:
+            x = self._validate_request(x)
+        except ValueError as e:
+            self._reject_locked(handle, "poison", str(e), now)
+            return
+        j = x.shape[1]
+        handle.num_samples = j
+        if deadline_s is None:
+            deadline_s = self.default_deadline_s
+        if deadline_s is not None:
+            if deadline_s <= 0:
+                handle._expire(
+                    f"deadline {deadline_s * 1e3:.3f} ms expired at "
+                    "admission", now=now,
+                )
+                self.stats["expired"] += 1
+                return
+            handle.deadline = now + deadline_s
+        if (
+            len(self._queue) + 1 > self.max_pending_requests
+            or self._pending_samples + j > self.max_pending_samples
+        ):
+            self._reject_locked(
+                handle, "overload",
+                f"overloaded: {len(self._queue)} requests / "
+                f"{self._pending_samples} samples pending (limits "
+                f"{self.max_pending_requests} / "
+                f"{self.max_pending_samples})",
+                now,
+            )
+            return
+        self._queue.append((x, handle))
+        self._pending_samples += j
+        self.stats["max_queue_depth"] = max(
+            self.stats["max_queue_depth"], self._pending_samples
+        )
+        if self._pending_samples >= self.max_batch:
+            self._flush_locked()
 
     def _reject_locked(
         self, handle: PendingResult, kind: str, reason: str, now: float
@@ -446,6 +462,10 @@ class ServeRuntime:
             return self._flush_locked()
 
     def _flush_locked(self) -> int:
+        with profiling.span(profiling.FLUSH):
+            return self._flush_queue_locked()
+
+    def _flush_queue_locked(self) -> int:
         if self._state == STOPPED:
             return 0
         self._expire_due_locked()
@@ -471,7 +491,14 @@ class ServeRuntime:
                     self._queue.append(item)
                     self._pending_samples += item[0].shape[1]
                 break
-            self._serve_batch(batch)
+            samples = sum(x.shape[1] for x, _ in batch)
+            with profiling.span(
+                profiling.BATCH,
+                first=batch[0][1].request_id, last=batch[-1][1].request_id,
+                requests=len(batch), samples=samples,
+                bucket=self.engine.bucket_for(samples),
+            ):
+                self._serve_batch(batch)
             served += len(batch)
         return served
 
@@ -494,8 +521,9 @@ class ServeRuntime:
         self.stats["engine_calls"] += 1
         if self.chaos is not None:
             self.chaos.on_engine_call(self.clock)
-        out = self.engine.forward(xcat)
-        jax.block_until_ready(out)
+        with profiling.span(profiling.FORWARD):
+            out = self.engine.forward(xcat)
+            jax.block_until_ready(out)
         return out
 
     def _serve_batch(self, batch, *, top: bool = True) -> None:
@@ -504,8 +532,9 @@ class ServeRuntime:
         request, or fail the batch for transient ones.  Only TOP-level
         outcomes feed the circuit breaker — bisection probes of one bad
         request must not open it."""
-        xs = [x for x, _ in batch]
-        xcat = xs[0] if len(xs) == 1 else np.concatenate(xs, axis=1)
+        with profiling.span(profiling.PACK):
+            xs = [x for x, _ in batch]
+            xcat = xs[0] if len(xs) == 1 else np.concatenate(xs, axis=1)
         error: Exception | None = None
         for attempt in range(self.max_retries + 1):
             if attempt > 0:
@@ -523,7 +552,8 @@ class ServeRuntime:
             hist = self.stats["batch_size_hist"]
             b = size_bucket(xcat.shape[1])
             hist[b] = hist.get(b, 0) + 1
-            scatter_results(batch, out, now=self.clock.now())
+            with profiling.span(profiling.SCATTER):
+                scatter_results(batch, out, now=self.clock.now())
             self.stats["completed"] += len(batch)
             self._on_engine_success()
             return
