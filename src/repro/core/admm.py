@@ -12,10 +12,12 @@ ADMM iterations (paper eq. 11):
     Lam^{k+1} = Lam_m^k + O_m^{k+1} - Z^{k+1}
 
 Notes on fidelity:
-- The Gram factor (Y_m Y_m^T + I/mu) is constant over k, so we Cholesky-
-  factorize it ONCE per layer (the Matlab reference does the same via a
-  cached inverse).  This is the dominant per-layer compute and is backed
-  by the ``gram`` Pallas kernel on TPU (repro.kernels.gram.ops).
+- The Gram matrix G_m = Y_m Y_m^T + I/mu is constant over k, so we
+  Cholesky-factorize it and turn the factor into G_m^{-1} ONCE per layer
+  (the Matlab reference does the same via a cached inverse); each
+  iteration's solve is then one float32 product R G_m^{-1}.  The Gram
+  product is backed by the ``gram`` Pallas kernel on TPU
+  (repro.kernels.gram.ops).
 - The paper defines P_eps with radius eps on the *Frobenius norm* even
   though the constraint is written ||Z||_F^2 <= eps; we follow the
   operational definition (radius), matching the released Matlab code and
@@ -135,15 +137,31 @@ def _worker_stats(y_workers: Array, t_workers: Array, mu: float, use_kernels: bo
     return a, chol, jitter
 
 
-def _o_update(a: Array, chol: Array, z: Array, lam: Array, mu: float) -> Array:
-    """O_m = (A_m + (Z - Lam_m)/mu) G_m^{-1} via the cached Cholesky factor."""
+def gram_inverse(chol: Array) -> Array:
+    """G^{-1} from the lower Cholesky factor of G: ``cho_solve`` against
+    the identity, once per layer.  Kept out of the ADMM scan because
+    XLA's blocked triangular solve inverts the factor's diagonal blocks
+    inside the op, where loop-invariant hoisting cannot reach them.  A
+    non-finite factor gives a non-finite inverse, so the layerwise
+    divergence guard still sees the failure."""
+    eye = jnp.eye(chol.shape[-1], dtype=chol.dtype)
+    return jax.scipy.linalg.cho_solve((chol, True), eye)
+
+
+def apply_gram_inverse(rhs: Array, g_inv: Array) -> Array:
+    """O = R G^{-1} as one float32 product: (G^{-1} R^T)^T, the columns
+    ``cho_solve((chol, True), R^T)`` returns.  ``HIGHEST`` keeps both
+    operands float32; the default TPU precision would round them to
+    bfloat16."""
+    return jnp.einsum(
+        "qj,ij->qi", rhs, g_inv, precision=jax.lax.Precision.HIGHEST
+    )
+
+
+def _o_update(a: Array, g_inv: Array, z: Array, lam: Array, mu: float) -> Array:
+    """O_m = (A_m + (Z - Lam_m)/mu) G_m^{-1} via the cached inverse."""
     rhs = a + (z[None] - lam) / mu          # (M, Q, n)
-
-    def solve_one(l_factor, r):
-        # Solve X G = R  ->  G^T X^T = R^T ; G symmetric -> G X^T = R^T.
-        return jax.scipy.linalg.cho_solve((l_factor, True), r.T).T
-
-    return jax.vmap(solve_one)(chol, rhs)
+    return jax.vmap(apply_gram_inverse)(rhs, g_inv)
 
 
 def admm_ridge_consensus(
@@ -215,6 +233,7 @@ def admm_ridge_consensus(
     dtype = y_workers.dtype
 
     a, chol, jitter = _worker_stats(y_workers, t_workers, mu, use_kernels=use_kernels)
+    g_inv = jax.vmap(gram_inverse)(chol)
 
     z_init = jnp.zeros((q, n), dtype) if z0 is None else z0.astype(dtype)
     state = ADMMState(
@@ -224,7 +243,7 @@ def admm_ridge_consensus(
     )
 
     def step(state: ADMMState, _):
-        o_new = _o_update(a, chol, state.z, state.lam, mu)
+        o_new = _o_update(a, g_inv, state.z, state.lam, mu)
         avg_in = o_new + state.lam                      # (M, Q, n)
         avg = consensus_fn(avg_in)                      # still (M, Q, n)
         consensus_err = consensus_lib.gossip_error(avg)
@@ -307,7 +326,8 @@ def worker_admm_iterations(
     policy: ConsensusPolicy | None = None,
     trace_every: int = 1,
 ):
-    """K eq.-11 iterations as a worker-local scan over the cached factor.
+    """K eq.-11 iterations as a worker-local scan over the cached G^{-1}
+    (``gram_inverse`` of the factor, formed before the scan).
 
     The shared inner loop of ``_admm_backend_path`` and the fused layer
     engine (``core.engine``): all cross-worker communication goes through
@@ -355,12 +375,15 @@ def worker_admm_iterations(
     ctx = backend.ctx()
     q, n = a.shape
     dtype = a.dtype
+    # Once per layer, outside the scan: each iteration only multiplies.
+    with profiling.scope(profiling.ADMM), profiling.scope(profiling.SOLVE):
+        g_inv = gram_inverse(chol)
 
     def solve(z, lam):
-        """O_m = (A_m + (Z - Lam_m)/mu) G_m^{-1} via the cached factor."""
+        """O_m = (A_m + (Z - Lam_m)/mu) G_m^{-1} via the cached inverse."""
         with profiling.scope(profiling.SOLVE):
             rhs = a + (z - lam) / mu
-            return jax.scipy.linalg.cho_solve((chol, True), rhs.T).T
+            return apply_gram_inverse(rhs, g_inv)
 
     def update(o, lam, avg):
         """The projection onto the eps-ball and the dual step."""

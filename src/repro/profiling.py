@@ -19,7 +19,7 @@ PROPAGATE = "dssfn.propagate"      # relu(W_l @ Y_{l-1})
 GRAM = "dssfn.gram"                # Y Y^T + I/mu and T Y^T (the fused kernel too)
 CHOLESKY = "dssfn.cholesky"        # the guarded Cholesky, retries included
 ADMM = "dssfn.admm"                # the K-iteration scan
-SOLVE = "admm.solve"               # right-hand side and cho_solve
+SOLVE = "admm.solve"               # G^{-1} once, then right-hand side and R G^{-1}
 MIX = "admm.mix"                   # the policy's consensus exchange
 UPDATE = "admm.update"             # projection and dual step
 
