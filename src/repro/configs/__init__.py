@@ -34,6 +34,7 @@ ALIASES = {
     "stablelm-3b": "stablelm_3b",
     "zamba2-2.7b": "zamba2_2_7b",
     "musicgen-medium": "musicgen_medium",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

@@ -6,6 +6,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import profiling
 from repro.models.config import ModelConfig
 from repro.nn import attention as attn_lib
 from repro.nn import moe as moe_lib
@@ -50,13 +51,14 @@ def apply_attention(
     v = (x @ p["wv"]).reshape(b, s, cfg.num_kv_heads, hd)
     q = shard(q, "batch", None, "tensor", None)
     k = shard(k, "batch", None, "tensor", None)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
 
     if cache is None:
         kr = attn_lib.repeat_kv(k, cfg.num_heads)
         vr = attn_lib.repeat_kv(v, cfg.num_heads)
-        if cfg.use_pallas_kernels and s % 128 == 0:
+        if cfg.use_pallas_kernels and s % 128 == 0 and cfg.attention_multiplier is None:
             from repro.kernels.flash_attention import flash_attention
 
             out = flash_attention(
@@ -67,7 +69,8 @@ def apply_attention(
             ).transpose(0, 2, 1, 3)
         else:
             out = attn_lib.chunked_causal_attention(
-                q, kr, vr, chunk_size=min(cfg.attn_chunk, s), window=window
+                q, kr, vr, chunk_size=min(cfg.attn_chunk, s), window=window,
+                scale=cfg.attention_multiplier,
             )
         new_cache = None
     else:
@@ -156,22 +159,24 @@ def apply_transformer_layer(
 def init_mamba_layer(key: jax.Array, cfg: ModelConfig) -> Params:
     d = cfg.d_model
     di = cfg.d_inner_eff
-    ds, h = cfg.ssm_state, cfg.ssm_heads
+    ds, h, g = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_groups
     dt = cfg.jnp_dtype
     ks = jax.random.split(key, 8)
     return {
         "ln": jnp.ones((d,), dt),
         "in_x": dense_init(ks[0], (d, di), dt),
         "in_z": dense_init(ks[1], (d, di), dt),
-        "in_b": dense_init(ks[2], (d, ds), dt),
-        "in_c": dense_init(ks[3], (d, ds), dt),
+        "in_b": dense_init(ks[2], (d, g * ds), dt),
+        "in_c": dense_init(ks[3], (d, g * ds), dt),
         "in_dt": dense_init(ks[4], (d, h), dt),
-        "conv_w": dense_init(ks[5], (cfg.conv_kernel, di), dt, scale=0.5),
-        "conv_b": jnp.zeros((di,), dt),
+        # One depthwise conv over the x, B and C channels (Mamba2's xBC).
+        "conv_w": dense_init(ks[5], (cfg.conv_kernel, di + 2 * g * ds), dt, scale=0.5),
+        "conv_b": jnp.zeros((di + 2 * g * ds,), dt),
         "a_log": jnp.log(
             jnp.linspace(1.0, 16.0, h, dtype=jnp.float32)
         ),  # A = -exp(a_log)
         "dt_bias": jnp.full((h,), -2.0, jnp.float32),
+        "d_skip": jnp.ones((h,), jnp.float32),  # y += D * x
         "gn": jnp.ones((di,), dt),
         "out": dense_init(ks[6], (di, d), dt),
     }
@@ -183,13 +188,18 @@ def apply_mamba_layer(
     cfg: ModelConfig,
     state: ssm_lib.SSMState | None,
 ) -> tuple[Array, ssm_lib.SSMState | None]:
-    """state=None -> training/prefill from zero state (full-sequence scan)."""
+    """Pre-norm Mamba2 mixer with its residual; state=None -> training/
+    prefill from zero state (full-sequence scan).
+
+    x, B and C are one causal depthwise conv's channels (then SiLU); heads
+    fall into ``ssm_groups`` groups that share B and C; the scan output
+    gains the skip ``D * x``; the gated RMSNorm reads ``y * silu(z)``."""
     b, s, d = x.shape
     di = cfg.d_inner_eff
-    h_heads, ds = cfg.ssm_heads, cfg.ssm_state
+    h_heads, ds, groups = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_groups
     dh = di // h_heads
     res = x
-    xn = rms_norm(x, p["ln"])
+    xn = rms_norm(x, p["ln"], cfg.norm_eps)
     xs = shard(xn @ p["in_x"], "batch", None, "tensor")
     z = shard(xn @ p["in_z"], "batch", None, "tensor")
     bm = xn @ p["in_b"]
@@ -197,42 +207,88 @@ def apply_mamba_layer(
     dt_pre = (xn @ p["in_dt"]).astype(jnp.float32) + p["dt_bias"]
     dt = jax.nn.softplus(dt_pre)
     a = -jnp.exp(p["a_log"])
+    xbc = jnp.concatenate([xs, bm, cm], axis=-1)
 
     decode = state is not None and s == 1
     if decode:
-        conv_prev = state.conv
-        xs, conv_new = ssm_lib.causal_conv1d(xs, p["conv_w"], p["conv_b"], conv_prev)
-        y, h_new = ssm_lib.ssm_decode_step(
-            xs.reshape(b, h_heads, dh), dt[:, 0], a, bm[:, 0], cm[:, 0], state.h
+        xbc, conv_new = ssm_lib.causal_conv1d(xbc, p["conv_w"], p["conv_b"], state.conv)
+        xs, bm, cm = jnp.split(xbc, [di, di + groups * ds], axis=-1)
+        y, h_new = _grouped(
+            lambda xg, dtg, ag, bg, cg, hg: ssm_lib.ssm_decode_step(
+                xg[:, 0], dtg[:, 0], ag, bg[:, 0], cg[:, 0], hg),
+            xs.reshape(b, 1, h_heads, dh), dt, a, bm, cm, state.h, groups, ds,
         )
         y = y.reshape(b, 1, di)
         new_state = ssm_lib.SSMState(h=h_new, conv=conv_new)
     else:
-        xs, conv_new = ssm_lib.causal_conv1d(xs, p["conv_w"], p["conv_b"])
-        h0 = jnp.zeros((b, h_heads, dh, ds), jnp.float32)
-        chunk = min(cfg.ssm_chunk, s)
-        pad = (-s) % chunk
-        if pad:
-            # dt=0 on padded steps: no decay (a=1), no input contribution.
-            xs = jnp.pad(xs, ((0, 0), (0, pad), (0, 0)))
-            dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-            bm = jnp.pad(bm, ((0, 0), (0, pad), (0, 0)))
-            cm = jnp.pad(cm, ((0, 0), (0, pad), (0, 0)))
-        if cfg.use_pallas_kernels and state is None:
-            from repro.kernels.ssm_scan import ssm_scan
-
-            y, h_new = ssm_scan(
-                xs.reshape(b, s + pad, h_heads, dh), dt, a, bm, cm, chunk=chunk
-            )
-        else:
-            y, h_new = ssm_lib.chunked_ssm_scan(
-                xs.reshape(b, s + pad, h_heads, dh), dt, a, bm, cm, h0, chunk=chunk
-            )
-        y = y[:, :s].reshape(b, s, di)
+        xbc, conv_new = ssm_lib.causal_conv1d(xbc, p["conv_w"], p["conv_b"])
+        xs, bm, cm = jnp.split(xbc, [di, di + groups * ds], axis=-1)
+        y, h_new = ssd_scan(xs, dt, a, bm, cm, cfg,
+                            pallas=cfg.use_pallas_kernels and state is None)
         new_state = ssm_lib.SSMState(h=h_new, conv=conv_new) if state is not None else None
-    y = rms_norm(y * jax.nn.silu(z), p["gn"])
+    y = _add_skip(y, xs, p["d_skip"], h_heads)
+    y = rms_norm(y * jax.nn.silu(z), p["gn"], cfg.norm_eps)
     out = y @ p["out"]
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
     return shard(res + out, "batch", None, None), new_state
+
+
+def ssd_scan(xs: Array, dt: Array, a: Array, bm: Array, cm: Array, cfg: ModelConfig,
+             *, pallas: bool = False) -> tuple[Array, Array]:
+    """The Mamba2 state-space scan of a whole sequence from a zero state,
+    chunked by ``cfg.ssm_chunk`` (the Pallas ``ssm_scan`` with ``pallas``),
+    under the ``backbone.ssd`` scope.  xs: (B, S, d_inner); dt: (B, S, H)
+    float32; a: (H,); bm, cm: (B, S, G*ds) -> y (B, S, d_inner) before
+    the D skip, and the last state (B, H, dh, ds) float32."""
+    b, s, di = xs.shape
+    h_heads, ds, groups = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_groups
+    dh = di // h_heads
+    h0 = jnp.zeros((b, h_heads, dh, ds), jnp.float32)
+    chunk = min(cfg.ssm_chunk, s)
+    pad = (-s) % chunk
+    if pad:
+        # dt=0 on padded steps: no decay (a=1), no input contribution.
+        xs = jnp.pad(xs, ((0, 0), (0, pad), (0, 0)))
+        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+        bm = jnp.pad(bm, ((0, 0), (0, pad), (0, 0)))
+        cm = jnp.pad(cm, ((0, 0), (0, pad), (0, 0)))
+    if pallas:
+        from repro.kernels.ssm_scan import ssm_scan
+
+        def scan(xg, dtg, ag, bg, cg, hg):
+            return ssm_scan(xg, dtg, ag, bg, cg, chunk=chunk)
+    else:
+        def scan(xg, dtg, ag, bg, cg, hg):
+            return ssm_lib.chunked_ssm_scan(xg, dtg, ag, bg, cg, hg, chunk=chunk)
+    with profiling.scope(profiling.SSD):
+        y, h_new = _grouped(scan, xs.reshape(b, s + pad, h_heads, dh), dt, a,
+                            bm, cm, h0, groups, ds)
+    return y[:, :s].reshape(b, s, di), h_new
+
+
+def _grouped(scan, x, dt, a, bm, cm, h, groups: int, ds: int):
+    """``scan`` once per group of heads, each group with its own B and C.
+    x: (B, S, H, dh); dt: (B, S, H); bm, cm: (B, S, G*ds); h: (B, H, dh, ds)."""
+    if groups == 1:
+        return scan(x, dt, a, bm, cm, h)
+    per = x.shape[2] // groups
+    ys, hs = [], []
+    for g in range(groups):
+        heads, cols = slice(g * per, (g + 1) * per), slice(g * ds, (g + 1) * ds)
+        y, hg = scan(x[:, :, heads], dt[:, :, heads], a[heads], bm[..., cols],
+                     cm[..., cols], h[:, heads])
+        ys.append(y)
+        hs.append(hg)
+    return jnp.concatenate(ys, axis=2), jnp.concatenate(hs, axis=1)
+
+
+def _add_skip(y: Array, x: Array, d_skip: Array, heads: int) -> Array:
+    """y + D x, per head, summed in float32."""
+    shape = y.shape
+    yh = y.reshape(shape[:-1] + (heads, -1)).astype(jnp.float32)
+    xh = x.reshape(shape[:-1] + (heads, -1)).astype(jnp.float32)
+    return (yh + d_skip[:, None] * xh).reshape(shape).astype(y.dtype)
 
 
 # ------------------------------------------------------------ xlstm layers

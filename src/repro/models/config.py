@@ -33,7 +33,15 @@ class ModelConfig:
     ssm_heads: int = 0
     d_inner: int = 0
     conv_kernel: int = 4
+    ssm_groups: int = 1            # Mamba2 n_groups: heads sharing one B and C
     shared_attn_period: int = 0    # hybrid: shared attn block every k layers
+    # hybrid with a published per-layer pattern ("mamba" | "attention")
+    layer_types: tuple[str, ...] = ()
+    position_embedding: str = "rope"   # rope | nope
+    attention_multiplier: float | None = None  # score scale; None = 1/sqrt(hd)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0   # scales each block's output before its residual add
+    norm_eps: float = 1e-6
     # xlstm
     slstm_period: int = 0          # every k-th layer is sLSTM (0 = none)
     # modality stubs

@@ -95,7 +95,8 @@ class HybridModel:
         dh = di // cfg.ssm_heads
         ssm_one = ssm_lib.SSMState(
             h=jnp.zeros((batch_size, cfg.ssm_heads, dh, cfg.ssm_state), jnp.float32),
-            conv=jnp.zeros((batch_size, cfg.conv_kernel - 1, di), cfg.jnp_dtype),
+            conv=jnp.zeros((batch_size, cfg.conv_kernel - 1,
+                            di + 2 * cfg.ssm_groups * cfg.ssm_state), cfg.jnp_dtype),
         )
         slots = min(max(max_len, 1), cfg.window) if cfg.attention == "swa" else max(max_len, 1)
         attn_one = attn_lib.init_kv_cache(

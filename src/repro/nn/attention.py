@@ -36,16 +36,19 @@ def chunked_causal_attention(
     chunk_size: int = 1024,
     window: int | None = None,
     q_offset: int = 0,
+    scale: float | None = None,
 ) -> Array:
     """Causal (optionally sliding-window) attention via online softmax.
 
     q: (B, Sq, H, hd); k, v: (B, Sk, H, hd) — KV already repeated to H.
     q_offset: absolute position of q[0] relative to k[0] (prefill: 0).
     window: sliding-window size (attend to keys with 0 <= pq - pk < window).
+    scale: the scores' multiplier (default 1/sqrt(hd)).
     """
     b, sq, h, hd = q.shape
     sk = k.shape[1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     qf = q.astype(jnp.float32) * scale
 
     num_chunks = -(-sk // chunk_size)

@@ -23,6 +23,13 @@ SOLVE = "admm.solve"               # G^{-1} once, then right-hand side and R G^{
 MIX = "admm.mix"                   # the policy's consensus exchange
 UPDATE = "admm.update"             # projection and dual step
 
+# Device scopes of a frozen backbone in a serving bucket program
+# (serve/engine.py, models/granite.py, models/blocks.py).
+BACKBONE = "features.backbone"     # the whole extractor: ids -> pooled features
+SSD = "backbone.ssd"               # the Mamba2 state-space scan
+ATTENTION = "backbone.attention"   # an attention mixer: projections, softmax, output
+MLP = "backbone.mlp"               # a SwiGLU MLP block with its norm
+
 # Host spans of the layer loop (core/layerwise.py).
 LAYER = "dssfn.layer"              # arg ``layer``
 DISPATCH = "dssfn.dispatch"        # enqueue of the layer program

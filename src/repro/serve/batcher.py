@@ -170,6 +170,8 @@ class MicroBatcher:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         if max_wait_us < 0:
             raise ValueError(f"max_wait_us must be >= 0, got {max_wait_us}")
+        if engine.takes_tokens:
+            raise ValueError("token engines serve through ServeRuntime")
         self.engine = engine
         self.max_batch = int(max_batch)
         self.max_wait_us = float(max_wait_us)
@@ -266,16 +268,20 @@ class MicroBatcher:
 
 
 def pack_fifo(
-    queue: list[tuple[np.ndarray, PendingResult]], max_batch: int
+    queue: list[tuple[np.ndarray, PendingResult]], max_batch: int, fits=None,
 ) -> list[list[tuple[np.ndarray, PendingResult]]]:
     """FIFO-pack queued requests into batches of <= ``max_batch``
     samples (a request larger than ``max_batch`` gets its own batch;
-    the engine chunks it).  Shared by the batcher and the runtime."""
+    the engine chunks it).  With ``fits(requests, max_batch)`` (the
+    engine's ``batch_fits``) a batch grows while ``fits`` holds for its
+    requests instead.  Shared by the batcher and the runtime."""
     batches: list[list[tuple[np.ndarray, PendingResult]]] = [[]]
     size = 0
     for item in queue:
         j = item[0].shape[1]
-        if batches[-1] and size + j > max_batch:
+        full = (size + j > max_batch if fits is None
+                else not fits([x for x, _ in batches[-1]] + [item[0]], max_batch))
+        if batches[-1] and full:
             batches.append([])
             size = 0
         batches[-1].append(item)

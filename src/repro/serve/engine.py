@@ -23,6 +23,12 @@ final readout ``O_L y_L`` — executed as ONE jitted program per
   program as operands (never baked jit constants — the backend cache's
   rule), so :meth:`reload` hot-swaps a newer same-shape artifact without
   a single recompile.
+- **Token requests.**  Under a token extractor (``granite-h-micro``) a
+  request column is one text's ids, right-padded with the pad id, and
+  the bucket programs are keyed by ``(texts, length)``: each batch of
+  texts is planned into groups of similar length, each padded to the
+  bucket with the fewest tokens that holds its texts and its longest
+  text (:meth:`plan_tokens`).  Ids stay integers into the program.
 - **Kernel routing.**  ``use_kernels=True`` routes each propagation
   through the ``matmul_relu`` Pallas kernel on 128-aligned shapes — the
   propagate half of the training engine's fused ``propagate_gram``
@@ -37,20 +43,34 @@ from typing import Callable, Hashable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import ssfn as ssfn_lib
 from repro.serve.export import ServeArtifact, load_artifact
-from repro.serve.features import parse_features
+from repro.serve.features import (
+    PAD_ID,
+    backbone_features,
+    parse_features,
+    stack_tokens,
+    text_lengths,
+)
 
 Array = jax.Array
 
 #: Default shape-bucket ladder: powers of two.  Only buckets a request
 #: size actually lands in are ever lowered.
 DEFAULT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128)
+#: Default ``(texts, length)`` buckets of a token extractor.
+DEFAULT_TOKEN_BUCKETS = ((1, 512), (2, 512), (1, 1024), (2, 1024), (1, 2048), (2, 2048),
+                         (1, 4096), (1, 8192))
 
 #: Bound on cached executables (one per (bucket, dtype) in practice —
 #: far below this; FIFO eviction keeps pathological dtype churn correct).
 _EXEC_CACHE_SIZE = 64
+
+
+def _tokens(bucket: tuple[int, int]) -> int:
+    return bucket[0] * bucket[1]
 
 
 def _aligned(*dims: int) -> bool:
@@ -84,13 +104,25 @@ class ServeEngine:
         self.dtype = jnp.dtype(dtype)
         self.use_kernels = bool(use_kernels)
 
-        buckets = tuple(sorted(set(buckets or DEFAULT_BUCKETS)))
-        if not buckets or buckets[0] < 1:
-            raise ValueError(f"buckets must be positive ints, got {buckets}")
-        self.buckets = buckets
-        self.max_batch = buckets[-1]
-
         self.extractor = parse_features(artifact.features)
+        self.takes_tokens = self.extractor is not None and self.extractor.takes_tokens
+        if self.takes_tokens:
+            pairs = {(int(t), int(n)) for t, n in (buckets or DEFAULT_TOKEN_BUCKETS)}
+            if not pairs or min(min(b) for b in pairs) < 1:
+                raise ValueError(f"token buckets must be (texts, length) pairs of "
+                                 f"positive ints, got {buckets}")
+            #: Fewest tokens first, so the first fit is the cheapest.
+            self.buckets = tuple(sorted(pairs, key=lambda b: (_tokens(b), b[1])))
+            self.max_batch = max(t for t, _ in self.buckets)
+            self.max_length = max(n for _, n in self.buckets)
+            #: A batch of texts may take the largest bucket's tokens.
+            self.max_tokens = max(_tokens(b) for b in self.buckets)
+        else:
+            buckets = tuple(sorted(set(buckets or DEFAULT_BUCKETS)))
+            if not buckets or buckets[0] < 1:
+                raise ValueError(f"buckets must be positive ints, got {buckets}")
+            self.buckets = buckets
+            self.max_batch = buckets[-1]
         #: Batch dimension requests arrive with (the extractor's input
         #: when one is configured, else the stack's own input dim).
         self.request_dim: int | None = (
@@ -153,6 +185,93 @@ class ServeEngine:
                 return b
         return self.max_batch
 
+    # ------------------------------------------------------------------
+    # Requests: what a runtime asks of the engine, for either kind
+    # ------------------------------------------------------------------
+    def admit(self, x) -> np.ndarray:
+        """A request checked against what this engine serves, as the
+        array the engine takes: column-stacked ``(P, j)`` finite values
+        (or ``(P,)``), or under a token extractor ``(S, j)`` ids
+        (``FeatureExtractor.admit``).  Raises ValueError."""
+        if self.takes_tokens:
+            return self.extractor.admit(x, self.max_length)
+        x = np.asarray(x)
+        if x.ndim == 1:
+            x = x[:, None]
+        if x.ndim != 2 or x.shape[1] < 1:
+            raise ValueError(
+                f"requests are column-stacked (P, j) arrays, got shape "
+                f"{tuple(x.shape)}"
+            )
+        expect = self.request_dim
+        if expect is not None and x.shape[0] != expect:
+            raise ValueError(
+                f"request has {x.shape[0]} feature rows, engine serves "
+                f"{expect}"
+            )
+        if not np.isfinite(x).all():
+            raise ValueError(
+                "request contains non-finite values (poison rejected at "
+                "admission)"
+            )
+        return x
+
+    def stack(self, xs: list[np.ndarray]) -> np.ndarray:
+        """Admitted requests side by side, as one ``forward`` input."""
+        if self.takes_tokens:
+            return stack_tokens(xs)
+        return xs[0] if len(xs) == 1 else np.concatenate(xs, axis=1)
+
+    def batch_fits(self, xs: list[np.ndarray], max_batch: int) -> bool:
+        """Whether admitted requests make one batch: at most ``max_batch``
+        columns; texts, whose plan takes at most ``max_tokens``."""
+        if self.takes_tokens:
+            return self.batch_tokens(xs)[1] <= self.max_tokens
+        return sum(x.shape[1] for x in xs) <= max_batch
+
+    def batch_tokens(self, xs: list[np.ndarray]) -> tuple[int, int]:
+        """Real tokens of a batch of requests, and the tokens of the bucket
+        programs the plan runs them in ((0, 0) without a token extractor)."""
+        if not self.takes_tokens:
+            return 0, 0
+        lengths = np.concatenate([text_lengths(x) for x in xs])
+        plan = self.plan_tokens(lengths)
+        return int(lengths.sum()), sum(_tokens(b) for _, b in plan)
+
+    def batch_bucket(self, xs: list[np.ndarray]) -> int:
+        """The bucket a batch runs in: its columns' bucket, or the tokens
+        of its plan's programs."""
+        if self.takes_tokens:
+            return self.batch_tokens(xs)[1]
+        return self.bucket_for(sum(x.shape[1] for x in xs))
+
+    def token_bucket(self, texts: int, length: int) -> tuple[int, int] | None:
+        """The ``(texts, length)`` bucket with the fewest tokens that holds
+        ``texts`` texts of at most ``length`` tokens, or None."""
+        return next((b for b in self.buckets if texts <= b[0] and length <= b[1]), None)
+
+    def plan_tokens(self, lengths) -> list[tuple[np.ndarray, tuple[int, int]]]:
+        """Group texts of ``lengths`` into ``(columns, bucket)`` programs.
+        Texts go longest first; a text joins the current group when the
+        group's bucket with it costs no more tokens than the group's
+        bucket and the text's own bucket apart, else it starts a group."""
+        lengths = np.asarray(lengths)
+        order = np.argsort(-lengths, kind="stable")
+        groups: list[list[int]] = []
+        for i in order:
+            if groups:
+                group = groups[-1]
+                longest = int(lengths[group[0]])
+                joined = self.token_bucket(len(group) + 1, longest)
+                apart = self.token_bucket(len(group), longest)
+                alone = self.token_bucket(1, int(lengths[i]))
+                if joined is not None and _tokens(joined) <= _tokens(apart) + _tokens(alone):
+                    group.append(int(i))
+                    continue
+            groups.append([int(i)])
+        return [(np.asarray(g), self.token_bucket(len(g), int(lengths[g[0]])))
+                for g in groups]
+
     def _chunks(self, j: int) -> list[int]:
         """Split a batch of ``j`` columns into per-executable chunk sizes."""
         out, left = [], j
@@ -165,8 +284,8 @@ class ServeEngine:
     # ------------------------------------------------------------------
     # Executable cache
     # ------------------------------------------------------------------
-    def _executable(self, bucket: int, dtype) -> Callable:
-        key = (int(bucket), jnp.dtype(dtype).name)
+    def _executable(self, bucket, dtype) -> Callable:
+        key = (bucket, jnp.dtype(dtype).name)
         jitted = self._exec_cache.get(key)
         if jitted is not None:
             self.cache_hits += 1
@@ -187,12 +306,16 @@ class ServeEngine:
         """The bucket program body (traceable, counter-free): features ->
         propagate stack -> readout.  ``_executable`` jits it with a
         lowering counter; ``lowering_texts`` lowers it standalone."""
-        x = x.astype(self.dtype)
+        if not self.takes_tokens:
+            x = x.astype(self.dtype)
         if self.extractor is not None:
             x = self._apply_features(feat_params, x)
         y = x
         for w in weights:
             y = self._propagate(w, y)
+        if self.takes_tokens:
+            # The pooled features too, for checks of the backbone alone.
+            return o_last @ y, x
         return o_last @ y
 
     def lowering_texts(
@@ -222,7 +345,10 @@ class ServeEngine:
             )
         self._materialize_features(request_dim)
         weights, o_last = self._device_weights
-        x_spec = jax.ShapeDtypeStruct((request_dim, int(bucket)), dtype)
+        if self.takes_tokens:
+            x_spec = jax.ShapeDtypeStruct(bucket[::-1], jnp.int32)
+        else:
+            x_spec = jax.ShapeDtypeStruct((request_dim, int(bucket)), dtype)
         lowered = jax.jit(self._forward_program).lower(
             weights, o_last, self._feat_params, x_spec
         )
@@ -240,6 +366,8 @@ class ServeEngine:
 
     def _apply_features(self, feat_params, x):
         ex = self.extractor
+        if ex.takes_tokens:
+            return backbone_features(feat_params, x, ex.model).astype(self.dtype)
         if ex.kind == "rff":
             w, b = feat_params
             return jnp.sqrt(2.0 / ex.dim) * jnp.cos(w @ x + b)
@@ -273,6 +401,8 @@ class ServeEngine:
             return
         if self._feat_params:
             return
+        if self.takes_tokens:
+            request_dim = None
         self.extractor.materialize(request_dim)
         if self.extractor.output_dim(request_dim) != self.artifact.input_dim:
             raise ValueError(
@@ -280,9 +410,7 @@ class ServeEngine:
                 f"{self.extractor.output_dim(request_dim)}-dim features, "
                 f"stack expects {self.artifact.input_dim}"
             )
-        self._feat_params = tuple(
-            jax.device_put(p) for p in self.extractor.params
-        )
+        self._feat_params = jax.device_put(self.extractor.params)
         self.request_dim = request_dim
 
     def _forward_bucket(self, x: Array) -> Array:
@@ -301,7 +429,11 @@ class ServeEngine:
 
     def forward(self, x) -> Array:
         """Logits ``O_L y_L`` for column-stacked requests ``x``:
-        (P, J) -> (Q, J); a single sample may arrive as (P,)."""
+        (P, J) -> (Q, J); a single sample may arrive as (P,).  Under a
+        token extractor, (S, J) integer ids, each column one text, and
+        the logits come back on the host."""
+        if self.takes_tokens:
+            return self._forward_tokens(x)
         x = jnp.asarray(x)
         if x.ndim == 1:
             x = x[:, None]
@@ -325,6 +457,47 @@ class ServeEngine:
             outs.append(self._forward_bucket(x[:, start:start + size]))
             start += size
         return jnp.concatenate(outs, axis=1)
+
+    def forward_features(self, ids) -> tuple[np.ndarray, np.ndarray]:
+        """Logits and the backbone's pooled features of token columns,
+        from the same bucket programs as :meth:`forward`:
+        (S, J) -> ((Q, J), (hidden, J)) on the host."""
+        if not self.takes_tokens:
+            raise ValueError("forward_features needs a token extractor")
+        return self._forward_tokens(ids, with_features=True)
+
+    def _forward_tokens(self, ids, *, with_features: bool = False):
+        """Plan the texts into bucket groups, run each, and return the
+        logits (and features) on the host: host slices compile nothing."""
+        ids = np.asarray(ids)
+        if ids.ndim == 1:
+            ids = ids[:, None]
+        if ids.ndim != 2 or not np.issubdtype(ids.dtype, np.integer):
+            raise ValueError(
+                f"token requests are column-stacked (S, J) integer ids, got "
+                f"{ids.dtype} of shape {tuple(ids.shape)}"
+            )
+        lengths = text_lengths(ids)
+        if lengths.min() < 1 or lengths.max() > self.max_length:
+            raise ValueError(
+                f"texts must hold 1..{self.max_length} tokens, got lengths "
+                f"{lengths.min()}..{lengths.max()}"
+            )
+        self._materialize_features(None)
+        weights, o_last = self._device_weights
+        logits = np.empty((self.num_classes, ids.shape[1]), np.float32)
+        feats = np.empty((self.extractor.dim, ids.shape[1]), np.float32) if with_features else None
+        for cols, (texts, length) in self.plan_tokens(lengths):
+            block = np.full((length, texts), PAD_ID, np.int32)
+            rows = min(length, ids.shape[0])
+            block[:rows, :len(cols)] = ids[:rows, cols]
+            out, phi = self._executable((texts, length), block.dtype)(
+                weights, o_last, self._feat_params, block
+            )
+            logits[:, cols] = np.asarray(out)[:, :len(cols)]
+            if with_features:
+                feats[:, cols] = np.asarray(phi)[:, :len(cols)]
+        return (logits, feats) if with_features else logits
 
     __call__ = forward
 

@@ -201,6 +201,10 @@ class ServeRuntime:
             "batches": 0,
             "batch_samples": 0,
             "batch_size_hist": {},
+            # Token engines: real tokens served, and the tokens of the
+            # bucket programs that served them.
+            "batch_tokens": 0,
+            "bucket_tokens": 0,
             "batch_failures": 0,
             "retries": 0,
             "quarantined": 0,
@@ -377,7 +381,7 @@ class ServeRuntime:
             )
             return
         try:
-            x = self._validate_request(x)
+            x = self.engine.admit(x)
         except ValueError as e:
             self._reject_locked(handle, "poison", str(e), now)
             return
@@ -424,28 +428,6 @@ class ServeRuntime:
         if kind != "overload":  # overload is routine load shedding
             self._event(f"reject-{kind}", reason)
 
-    def _validate_request(self, x) -> np.ndarray:
-        x = np.asarray(x)
-        if x.ndim == 1:
-            x = x[:, None]
-        if x.ndim != 2 or x.shape[1] < 1:
-            raise ValueError(
-                f"requests are column-stacked (P, j) arrays, got shape "
-                f"{tuple(x.shape)}"
-            )
-        expect = self.engine.request_dim
-        if expect is not None and x.shape[0] != expect:
-            raise ValueError(
-                f"request has {x.shape[0]} feature rows, engine serves "
-                f"{expect}"
-            )
-        if not np.isfinite(x).all():
-            raise ValueError(
-                "request contains non-finite values (poison rejected at "
-                "admission)"
-            )
-        return x
-
     # ------------------------------------------------------------------
     # The serving loop
     # ------------------------------------------------------------------
@@ -483,7 +465,7 @@ class ServeRuntime:
         queue, self._queue = self._queue, []
         self._pending_samples = 0
         served = 0
-        batches = pack_fifo(queue, self.max_batch)
+        batches = pack_fifo(queue, self.max_batch, self.engine.batch_fits)
         for i, batch in enumerate(batches):
             if self._breaker == BREAKER_OPEN:
                 # Re-opened mid-flush: requeue the untouched remainder.
@@ -492,11 +474,11 @@ class ServeRuntime:
                     self._pending_samples += item[0].shape[1]
                 break
             samples = sum(x.shape[1] for x, _ in batch)
+            bucket = self.engine.batch_bucket([x for x, _ in batch])
             with profiling.span(
                 profiling.BATCH,
                 first=batch[0][1].request_id, last=batch[-1][1].request_id,
-                requests=len(batch), samples=samples,
-                bucket=self.engine.bucket_for(samples),
+                requests=len(batch), samples=samples, bucket=bucket,
             ):
                 self._serve_batch(batch)
             served += len(batch)
@@ -534,7 +516,7 @@ class ServeRuntime:
         request must not open it."""
         with profiling.span(profiling.PACK):
             xs = [x for x, _ in batch]
-            xcat = xs[0] if len(xs) == 1 else np.concatenate(xs, axis=1)
+            xcat = self.engine.stack(xs)
         error: Exception | None = None
         for attempt in range(self.max_retries + 1):
             if attempt > 0:
@@ -552,6 +534,9 @@ class ServeRuntime:
             hist = self.stats["batch_size_hist"]
             b = size_bucket(xcat.shape[1])
             hist[b] = hist.get(b, 0) + 1
+            real, padded = self.engine.batch_tokens(xs)
+            self.stats["batch_tokens"] += real
+            self.stats["bucket_tokens"] += padded
             with profiling.span(profiling.SCATTER):
                 scatter_results(batch, out, now=self.clock.now())
             self.stats["completed"] += len(batch)
