@@ -13,11 +13,13 @@ import jax
 from benchmarks.common import (
     ADMM_ITERS, DATA_SCALE, HIDDEN_EXTRA, csv_row, timed,
 )
-from repro.core import consensus, layerwise, ssfn, topology
+from repro.core import layerwise, ssfn, topology
+from repro.core.policy import Gossip
 from repro.data import paper_dataset, partition_workers
 
 M = 20
-DEGREES = [1, 2, 3, 4, 6, 8, 10]
+# A ring of degree d needs 2d+1 <= M distinct neighbours.
+DEGREES = [1, 2, 3, 4, 6, 8, 9]
 
 
 def run(verbose: bool = True) -> list[str]:
@@ -33,10 +35,10 @@ def run(verbose: bool = True) -> list[str]:
     for d in DEGREES:
         h = topology.circular_mixing_matrix(M, d)
         rounds = topology.gossip_rounds_for_tolerance(h, 1e-8)
-        cfn = consensus.make_consensus_fn("gossip", h=h, num_rounds=rounds)
+        policy = Gossip(rounds=rounds, topology=topology.Ring(d))
         (_, log), t = timed(
             layerwise.train_decentralized_ssfn, xw, tw, cfg,
-            jax.random.PRNGKey(0), consensus_fn=cfn, gossip_rounds=rounds,
+            jax.random.PRNGKey(0), policy=policy,
         )
         derived = (
             f"degree={d};B={rounds};exchanges_per_layer={rounds * ADMM_ITERS};"

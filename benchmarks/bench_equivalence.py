@@ -12,7 +12,8 @@ import jax
 from benchmarks.common import (
     ADMM_ITERS, DATA_SCALE, HIDDEN_EXTRA, NUM_LAYERS, NUM_WORKERS, csv_row, timed,
 )
-from repro.core import consensus, equivalence, layerwise, ssfn, topology
+from repro.core import equivalence, layerwise, ssfn, topology
+from repro.core.policy import Gossip
 from repro.data import paper_dataset, partition_workers
 
 DATASETS = ["vowel", "satimage", "letter", "mnist"]
@@ -47,10 +48,9 @@ def run(verbose: bool = True) -> list[str]:
         xw, tw = partition_workers(data.x_train, data.t_train, NUM_WORKERS)
         h = topology.circular_mixing_matrix(NUM_WORKERS, 4)  # paper: d=4
         rounds = topology.gossip_rounds_for_tolerance(h, 1e-8)
-        cfn = consensus.make_consensus_fn("gossip", h=h, num_rounds=rounds)
+        policy = Gossip(rounds=rounds, topology=topology.Ring(4))
         (params_d, log_d), t_dec = timed(
-            layerwise.train_decentralized_ssfn, xw, tw, cfg, key,
-            consensus_fn=cfn, gossip_rounds=rounds,
+            layerwise.train_decentralized_ssfn, xw, tw, cfg, key, policy=policy,
         )
         accs = {
             "cen_train": layerwise.accuracy(params_c, data.x_train, data.y_train, q),

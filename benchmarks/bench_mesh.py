@@ -2,7 +2,7 @@
 bytes moved, compile-once engine vs legacy re-trace, and parity.
 
 The tentpole measurement for the compile-once layer engine: the SAME
-worker program (``core.admm._admm_backend_path``) timed under
+worker program (``core.engine.layer_program`` at l=0) timed under
 
   - ``SimulatedBackend``  (vmap worker axis, single device), and
   - ``MeshBackend``       (shard_map, one worker per device slot),

@@ -1,7 +1,7 @@
 """The wire-budget check: lowered collectives vs declared eq.-15 wire.
 
 For each policy the checker lowers (never executes) the production hot
-program — ``admm.worker_admm_iterations`` with ``trace_every=0`` — on a
+program — the layer engine's program with ``trace_every=0`` — on a
 real worker mesh and compares what the compiled HLO actually contains
 against what the policy declares:
 
@@ -73,30 +73,21 @@ def hot_program_texts(
     backend, policy, *, num_iters: int, n: int = 16, q: int = 3,
     j_per: int = 8,
 ):
-    """Lower the ``trace_every=0`` ADMM worker program under ``policy``
-    and return the backend's ``{"stablehlo", "hlo"}`` texts."""
+    """Lower the engine's ``trace_every=0`` layer program (l=0, no
+    propagation) under ``policy`` and return the backend's
+    ``{"stablehlo", "hlo"}`` texts."""
     import jax
-    import jax.numpy as jnp
 
-    from repro.core import admm
+    from repro.core import engine
 
     m = backend.num_workers
     ky, kt = jax.random.split(jax.random.PRNGKey(0))
     yw = jax.random.normal(ky, (m, n, j_per))
     tw = jax.random.normal(kt, (m, q, j_per))
-    z0 = jnp.zeros((q, n))
-
-    def worker(y_m, t_m, z0r):
-        a, chol, _ = admm._worker_stats_local(y_m, t_m, 1e-2, False)
-        return admm.worker_admm_iterations(
-            backend, a, chol, y_m, t_m, z0r, mu=1e-2, eps_radius=6.0,
-            num_iters=num_iters, policy=policy, trace_every=0,
-        )
-
-    return backend.lowering_texts(
-        worker, yw, tw, replicated=(z0,),
-        key=("spmdlint-wire", policy, num_iters), policy=policy,
-    )
+    return engine.layer_program(
+        backend, yw, tw, None, mu=1e-2, eps_radius=6.0, num_iters=num_iters,
+        policy=policy, trace_every=0,
+    ).lowering_texts()
 
 
 def _stablehlo_permute_payloads(text: str) -> list[tuple[str, int]]:

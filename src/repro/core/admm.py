@@ -28,13 +28,12 @@ Notes on fidelity:
 """
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, Callable, NamedTuple
+from typing import TYPE_CHECKING, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import profiling
-from repro.core import consensus as consensus_lib
 from repro.core.policy import ConsensusPolicy
 
 if TYPE_CHECKING:  # avoid a circular import at runtime (backend imports policy)
@@ -50,12 +49,6 @@ def project_frobenius(z: Array, radius: float) -> Array:
     return z * scale
 
 
-class ADMMState(NamedTuple):
-    o: Array      # (M, Q, n) per-worker primal variables
-    z: Array      # (Q, n) consensus variable (replicated)
-    lam: Array    # (M, Q, n) scaled duals
-
-
 class ADMMTrace(NamedTuple):
     objective: Array        # (K,) global objective sum_m ||T_m - Z Y_m||^2
     primal_residual: Array  # (K,) ||O_m - Z|| aggregated
@@ -69,7 +62,7 @@ class ADMMResult(NamedTuple):
     lam: Array
     trace: "ADMMTrace | None"   # None when trace_every=0 (hot path)
     #: Per-worker guarded-Cholesky jitter level (int32; 0 = factored
-    #: clean).  None on paths predating the guard (legacy consensus_fn).
+    #: clean).
     jitter: "Array | None" = None
 
 
@@ -116,27 +109,6 @@ def guarded_cholesky(
     return chol, k
 
 
-def _worker_stats(y_workers: Array, t_workers: Array, mu: float, use_kernels: bool = False):
-    """Per-worker A_m = T_m Y_m^T and guarded Cholesky of
-    G_m = Y_m Y_m^T + I/mu (plus the per-worker jitter level).
-
-    use_kernels=True routes the Gram product through the Pallas ``gram``
-    kernel (TPU hot-path; interpret mode elsewhere).
-    """
-    n, j = y_workers.shape[1], y_workers.shape[2]
-    if use_kernels and n % 128 == 0 and j % 128 == 0:
-        from repro.kernels.gram import gram as gram_kernel
-
-        gram = jax.vmap(lambda ym: gram_kernel(ym, mu=mu))(y_workers)
-        gram = gram.astype(y_workers.dtype)
-    else:
-        gram = jnp.einsum("mij,mkj->mik", y_workers, y_workers)
-        gram = gram + (1.0 / mu) * jnp.eye(n, dtype=y_workers.dtype)
-    chol, jitter = jax.vmap(guarded_cholesky)(gram)
-    a = jnp.einsum("mqj,mnj->mqn", t_workers, y_workers)
-    return a, chol, jitter
-
-
 def gram_inverse(chol: Array) -> Array:
     """G^{-1} from the lower Cholesky factor of G: ``cho_solve`` against
     the identity, once per layer.  Kept out of the ADMM scan because
@@ -158,12 +130,6 @@ def apply_gram_inverse(rhs: Array, g_inv: Array) -> Array:
     )
 
 
-def _o_update(a: Array, g_inv: Array, z: Array, lam: Array, mu: float) -> Array:
-    """O_m = (A_m + (Z - Lam_m)/mu) G_m^{-1} via the cached inverse."""
-    rhs = a + (z[None] - lam) / mu          # (M, Q, n)
-    return jax.vmap(apply_gram_inverse)(rhs, g_inv)
-
-
 def admm_ridge_consensus(
     y_workers: Array,
     t_workers: Array,
@@ -171,14 +137,17 @@ def admm_ridge_consensus(
     mu: float,
     eps_radius: float,
     num_iters: int,
-    consensus_fn: Callable[[Array], Array] | None = None,
     backend: "ConsensusBackend | None" = None,
     policy: ConsensusPolicy | None = None,
-    z0: Array | None = None,
     use_kernels: bool = False,
     trace_every: int = 1,
 ) -> ADMMResult:
     """Run K iterations of consensus ADMM (paper Algorithm 1, lines 5-10).
+
+    A standalone solve runs the layer engine's l=0 program (no
+    propagation, Z from zero), so it shares one executable-cache entry
+    with a training layer 0 of the same hyper-parameters and shapes.
+    Traces report worker 0.
 
     y_workers: (M, n, J_m) per-worker feature matrices (equal shard sizes,
         matching the paper's uniform division of the training set).
@@ -193,83 +162,24 @@ def admm_ridge_consensus(
         ``QuantizedGossip``, ``LossyGossip``, ``StaleMixing``); defaults
         to the backend's own policy.  Policy state (quantizer keys,
         staleness buffers) is threaded through the ADMM scan carry.
-    consensus_fn: legacy batched (M, Q, n) -> (M, Q, n) averaging
-        primitive for simulations with an *arbitrary* dense mixing matrix
-        H (``make_consensus_fn('gossip', h=...)``).  Mutually exclusive
-        with ``backend``/``policy``; ring topologies should prefer a
-        gossip-policy backend, which expresses the same mixing as peer
-        exchanges.
     trace_every: convergence-trace stride (``worker_admm_iterations``):
         1 = per-iteration traces (default), 0 = no traces and NO
         trace collectives in the lowered program (``result.trace`` is
-        None), N > 1 = every N-th iteration.  Backend path only.
+        None), N > 1 = every N-th iteration.
     """
-    if consensus_fn is not None and (backend is not None or policy is not None):
-        raise ValueError("pass either consensus_fn or backend/policy, not both")
-    if consensus_fn is None:
-        from repro.core.backend import SimulatedBackend
+    from repro.core import engine as engine_lib
+    from repro.core.backend import SimulatedBackend
 
-        if backend is None:
-            backend = SimulatedBackend(y_workers.shape[0])
-        return _admm_backend_path(
-            y_workers,
-            t_workers,
-            backend=backend,
-            policy=policy,
-            mu=mu,
-            eps_radius=eps_radius,
-            num_iters=num_iters,
-            z0=z0,
-            use_kernels=use_kernels,
-            trace_every=trace_every,
-        )
-    if trace_every != 1:
-        raise ValueError(
-            "trace_every is a backend-path knob; the legacy consensus_fn "
-            "simulation always traces every iteration"
-        )
-    m, n = y_workers.shape[0], y_workers.shape[1]
-    q = t_workers.shape[1]
-    dtype = y_workers.dtype
-
-    a, chol, jitter = _worker_stats(y_workers, t_workers, mu, use_kernels=use_kernels)
-    g_inv = jax.vmap(gram_inverse)(chol)
-
-    z_init = jnp.zeros((q, n), dtype) if z0 is None else z0.astype(dtype)
-    state = ADMMState(
-        o=jnp.zeros((m, q, n), dtype),
-        z=z_init,
-        lam=jnp.zeros((m, q, n), dtype),
+    if backend is None:
+        backend = SimulatedBackend(y_workers.shape[0])
+    step = engine_lib.fused_layer_step(
+        backend, y_workers, t_workers, None,
+        mu=mu, eps_radius=eps_radius, num_iters=num_iters,
+        use_kernels=use_kernels, policy=policy, trace_every=trace_every,
     )
-
-    def step(state: ADMMState, _):
-        o_new = _o_update(a, g_inv, state.z, state.lam, mu)
-        avg_in = o_new + state.lam                      # (M, Q, n)
-        avg = consensus_fn(avg_in)                      # still (M, Q, n)
-        consensus_err = consensus_lib.gossip_error(avg)
-        # Every worker applies P_eps to its own consensus estimate; under
-        # exact consensus these coincide.  We track worker 0's Z as "the" Z
-        # and keep per-worker Z for the gossip-mode dual update.
-        z_workers = jax.vmap(lambda v: project_frobenius(v, eps_radius))(avg)
-        z_new = z_workers[0]
-        lam_new = state.lam + o_new - z_workers
-        obj = jnp.sum(
-            jax.vmap(lambda t_m, y_m: jnp.sum((t_m - z_new @ y_m) ** 2))(
-                t_workers, y_workers
-            )
-        )
-        primal = jnp.linalg.norm(o_new - z_workers)
-        dual = jnp.linalg.norm(z_new - state.z)
-        new_state = ADMMState(o=o_new, z=z_new, lam=lam_new)
-        return new_state, (obj, primal, dual, consensus_err)
-
-    state, (objs, primals, duals, cerrs) = jax.lax.scan(
-        step, state, None, length=num_iters
-    )
-    trace = ADMMTrace(objs, primals, duals, cerrs)
     return ADMMResult(
-        o_star=state.z, o_workers=state.o, lam=state.lam, trace=trace,
-        jitter=jitter,
+        o_star=step.o_star, o_workers=step.o_workers, lam=step.lam,
+        trace=step.trace, jitter=step.jitter,
     )
 
 
@@ -277,12 +187,16 @@ def _worker_stats_local(y_m: Array, t_m: Array, mu: float, use_kernels: bool):
     """Worker-local A_m = T_m Y_m^T and guarded Cholesky of
     G_m = Y_m Y_m^T + I/mu (plus this worker's jitter level).
 
-    The local view of ``_worker_stats`` for SPMD execution: same math, no
-    worker axis, same Pallas ``gram`` kernel routing on aligned shapes.
+    use_kernels=True routes the Gram product through the Pallas ``gram``
+    kernel on aligned shapes (interpret mode off-TPU).
     """
     n, j = y_m.shape
+    if use_kernels:
+        from repro.kernels.common import aligned
+
+        use_kernels = aligned(n, j)
     with profiling.scope(profiling.GRAM):
-        if use_kernels and n % 128 == 0 and j % 128 == 0:
+        if use_kernels:
             from repro.kernels.gram import gram as gram_kernel
 
             gram = gram_kernel(y_m, mu=mu).astype(y_m.dtype)
@@ -329,8 +243,8 @@ def worker_admm_iterations(
     """K eq.-11 iterations as a worker-local scan over the cached G^{-1}
     (``gram_inverse`` of the factor, formed before the scan).
 
-    The shared inner loop of ``_admm_backend_path`` and the fused layer
-    engine (``core.engine``): all cross-worker communication goes through
+    The inner loop of the layer engine's worker program
+    (``core.engine``): all cross-worker communication goes through
     ``policy.mix`` (default: the backend's policy) on the backend's
     collective context, and the policy's per-round state — quantizer PRNG
     keys, staleness buffers — rides in the scan carry.  Each worker
@@ -502,68 +416,6 @@ def worker_admm_iterations(
             chunk, init, None, length=num_iters // trace_every
         )
         return state, traces
-
-
-def _admm_backend_path(
-    y_workers: Array,
-    t_workers: Array,
-    *,
-    backend: "ConsensusBackend",
-    mu: float,
-    eps_radius: float,
-    num_iters: int,
-    z0: Array | None,
-    use_kernels: bool,
-    policy: ConsensusPolicy | None = None,
-    trace_every: int = 1,
-) -> ADMMResult:
-    """Eq.-11 iteration as a worker-local SPMD program.
-
-    The same traced program runs under ``SimulatedBackend`` (vmap) and
-    ``MeshBackend`` (shard_map); traces report worker 0, matching the
-    batched path.  The worker program is compiled through the backend's
-    executable cache: ``z0`` rides along as a replicated operand (NOT a
-    closed-over constant) so one cached executable serves every solve
-    with the same hyper-parameters and operand shapes.
-    """
-    m = y_workers.shape[0]
-    if m != backend.num_workers:
-        raise ValueError(
-            f"y_workers has {m} worker shards, backend expects {backend.num_workers}"
-        )
-    policy = policy if policy is not None else backend.policy
-    policy.validate(backend.num_workers)
-    trace_every = validate_trace_every(trace_every, num_iters)
-    q, n = t_workers.shape[1], y_workers.shape[1]
-    dtype = y_workers.dtype
-    z_init = jnp.zeros((q, n), dtype) if z0 is None else z0.astype(dtype)
-
-    def worker(y_m: Array, t_m: Array, z_init_rep: Array):
-        a, chol, jitter = _worker_stats_local(y_m, t_m, mu, use_kernels)
-        state, traces = worker_admm_iterations(
-            backend, a, chol, y_m, t_m, z_init_rep,
-            mu=mu, eps_radius=eps_radius, num_iters=num_iters, policy=policy,
-            trace_every=trace_every,
-        )
-        return state, traces, jitter
-
-    # trace_every changes the traced output pytree (no trace leaves at
-    # 0, K/N-long leaves at N>1), so it must key the executable cache.
-    cache_key = (
-        "admm_ridge", float(mu), float(eps_radius), int(num_iters),
-        bool(use_kernels), trace_every,
-    )
-    (o_w, z_w, lam_w), traces, jitter_w = backend.run(
-        worker, y_workers, t_workers, replicated=(z_init,), key=cache_key,
-        policy=policy,
-    )
-    trace = None
-    if traces is not None:
-        objs, primals, duals, cerrs = traces
-        trace = ADMMTrace(objs[0], primals[0], duals[0], cerrs[0])
-    return ADMMResult(
-        o_star=z_w[0], o_workers=o_w, lam=lam_w, trace=trace, jitter=jitter_w
-    )
 
 
 def centralized_ridge_admm(

@@ -200,7 +200,7 @@ class ConsensusBackend(abc.ABC):
         policy: the consensus policy this program runs under, when it is
             not the backend default.  ``fn`` must close over the policy
             object itself (policies are static config; see
-            ``admm._admm_backend_path``); passing it here makes it part
+            ``engine.layer_program``); passing it here makes it part
             of the executable-cache key, so one lowering per
             (program, policy) pair and no stale-executable reuse.
         """

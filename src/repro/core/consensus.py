@@ -28,16 +28,11 @@ and composed per training run is the job of the ``ConsensusPolicy``
 strategy objects in ``repro.core.policy`` (``ExactMean``, ``RingGossip``,
 ``QuantizedGossip``, ``LossyGossip``, ``StaleMixing``), which call back
 into these primitives.  The SPMD-side extras — the lossy schedule hop and
-the stochastic quantizer — live here for the same reason.
-
-``make_consensus_fn`` (the legacy batched dense-H factory) is deprecated:
-prefer a policy plus a backend, which run the identical mixing as peer
-exchanges under both the simulation and the mesh.
+the stochastic quantizer — live here for the same reason.  The dense-H
+functions (``gossip_average``, ``exact_average``, ``gossip_error``) are
+the plain reference the policies' peer exchanges are tested against.
 """
 from __future__ import annotations
-
-import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -506,35 +501,3 @@ def quantize_nearest(x: jax.Array, bits: int) -> jax.Array:
     hi = jnp.max(x)
     scale = jnp.maximum(hi - lo, 1e-12) / levels
     return lo + jnp.round((x - lo) / scale) * scale
-
-
-def make_consensus_fn(
-    mode: str,
-    *,
-    h: np.ndarray | None = None,
-    num_rounds: int = 1,
-):
-    """Factory for a worker-axis consensus function f: (M, ...) -> (M, ...).
-
-    mode = 'exact'  : true mean (production path; == one all-reduce)
-    mode = 'gossip' : B rounds of x <- Hx (paper-faithful simulation)
-
-    .. deprecated::
-        Stale alias kept for the batched dense-H simulation path.  New
-        code should pass a ``repro.core.policy`` ConsensusPolicy to a
-        ``ConsensusBackend`` — the same mixing expressed as peer
-        exchanges, valid on the mesh as well as in simulation.
-    """
-    warnings.warn(
-        "make_consensus_fn is deprecated; pass a ConsensusPolicy "
-        "(repro.core.policy) to a ConsensusBackend instead",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    if mode == "exact":
-        return exact_average
-    if mode == "gossip":
-        if h is None:
-            raise ValueError("gossip mode requires a mixing matrix h")
-        return functools.partial(gossip_average, h=h, num_rounds=num_rounds)
-    raise ValueError(f"unknown consensus mode {mode!r}")

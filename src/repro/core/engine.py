@@ -58,16 +58,15 @@ class LayerStepResult(NamedTuple):
     jitter: "Array | None" = None
 
 
-def _aligned(*dims: int) -> bool:
-    return all(d % 128 == 0 for d in dims)
-
-
 def _propagate_and_stats(w, y_m, t_m, mu: float, use_kernels: bool):
     """relu(W @ Y_m) then (A_m, chol(G_m), jitter) — fused on aligned
     shapes; the Cholesky is the guarded (self-healing) factorization."""
-    n_out, n_in = w.shape
-    j = y_m.shape[1]
-    if use_kernels and _aligned(n_out, n_in, j):
+    fused = False
+    if use_kernels:
+        from repro.kernels.common import aligned
+
+        fused = aligned(*w.shape, y_m.shape[1])
+    if fused:
         from repro.kernels.propagate_gram import propagate_gram
 
         # The fused kernel's propagation counts as Gram work.
@@ -80,7 +79,7 @@ def _propagate_and_stats(w, y_m, t_m, mu: float, use_kernels: bool):
             a = t_m @ y_new.T
         return y_new, a, chol, jitter
     # Unfused: plain propagation, then the same stats construction (and
-    # gram-kernel routing) the direct ADMM path uses.
+    # gram-kernel routing) the l=0 step uses.
     with profiling.scope(profiling.PROPAGATE):
         y_new = jax.nn.relu(w @ y_m)
     a, chol, jitter = admm_lib._worker_stats_local(y_new, t_m, mu, use_kernels)

@@ -12,12 +12,11 @@ The *only* difference between the two is where the data lives and how the
 consensus mean in the Z-update is computed — which is the paper's central
 claim of centralized equivalence.
 
-Execution: the backend path runs through the compile-once layer engine
+Execution: every layer runs through the compile-once layer engine
 (``core.engine``) — propagation, Gram/Cholesky and the K-iteration ADMM
 scan fuse into one cached SPMD program per layer, traces accumulate on
 device and are fetched once after the loop, and the self-size-estimation
-stop costs exactly one scalar fetch per layer.  The legacy dense-H
-``consensus_fn`` simulation keeps the original per-call loop.
+stop costs exactly one scalar fetch per layer.
 """
 from __future__ import annotations
 
@@ -25,7 +24,7 @@ import os
 import time
 import warnings
 from dataclasses import dataclass, field
-from typing import Callable
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -90,89 +89,119 @@ def _key_data(key: jax.Array) -> jax.Array:
     return key
 
 
+class _LoopState(NamedTuple):
+    """Where the layer loop stands: what a checkpoint holds, and what
+    resume and divergence rollback restore.
+
+    ``layer`` is the next layer to solve and ``y_workers`` the features
+    it reads.  ``o``, ``traces`` and ``jitter`` hold one entry per
+    completed layer (the traces stay on the device until the loop ends).
+    ``r`` is the whole draw of random matrices, future layers included:
+    divergence rollback perturbs the key for future layers, so the key
+    alone no longer determines them.  It is None only in a checkpoint
+    written before the random matrices were stored.
+    """
+
+    layer: int
+    key: jax.Array
+    o: tuple = ()
+    traces: tuple = ()
+    jitter: tuple = ()
+    comm: int = 0
+    prev_cost: float | None = None
+    y_workers: Array | None = None
+    r: tuple | None = None
+
+
+def _layer_weight(state: _LoopState, q: int) -> Array | None:
+    """W_l = [V_Q O_{l-1} ; R_l] for the layer ``state`` stands before;
+    None at layer 0, whose solve reads the input features directly."""
+    if state.layer == 0:
+        return None
+    with profiling.span(profiling.BUILD_WEIGHT):
+        return ssfn_lib.build_weight(state.o[-1], state.r[state.layer - 1], q)
+
+
 def _save_checkpoint(
-    directory: str, *, layer_next: int, key, y_workers, o_list,
-    step: engine_lib.LayerStepResult, dev_traces, comm: int,
-    prev_cost: float | None, active_mask: np.ndarray,
-    r_list=None, jitter_list=None,
+    directory: str, state: _LoopState, *,
+    step: engine_lib.LayerStepResult, active_mask: np.ndarray,
 ) -> str:
-    """Elastic-resume state after ``layer_next`` completed layers: layer
-    features, per-layer readouts, the last solve's worker primals/duals,
-    the RNG key, the random matrices ACTUALLY used so far (divergence
-    rollback perturbs the key for future layers, so the key alone no
-    longer determines them), membership, and the device traces
-    accumulated so far."""
+    """Elastic-resume state after ``state.layer`` completed layers: the
+    loop state, the last solve's worker primals/duals and the
+    membership mask."""
     from repro.checkpoint.store import save_pytree
 
-    state = {
-        "layer_next": np.int64(layer_next),
-        "key": _key_data(key),
-        "y_workers": y_workers,
-        "o": {str(i): o for i, o in enumerate(o_list)},
+    tree = {
+        "layer_next": np.int64(state.layer),
+        "key": _key_data(state.key),
+        "y_workers": np.asarray(jax.device_get(state.y_workers)),
+        "o": {str(i): o for i, o in enumerate(state.o)},
         "o_workers": step.o_workers,
         "lam": step.lam,
-        "comm": np.int64(comm),
-        "prev_cost": np.float64(np.nan if prev_cost is None else prev_cost),
+        "comm": np.int64(state.comm),
+        "prev_cost": np.float64(
+            np.nan if state.prev_cost is None else state.prev_cost
+        ),
         "membership": np.asarray(active_mask, np.float64),
+        "r": {str(i): r for i, r in enumerate(state.r)},
     }
-    if r_list is not None:
-        state["r"] = {str(i): r for i, r in enumerate(r_list)}
-    if jitter_list:
-        state["jit"] = np.stack(
-            [np.asarray(j, np.int32) for j in jitter_list]
+    if state.jitter:
+        tree["jit"] = np.stack(
+            [np.asarray(j, np.int32) for j in state.jitter]
         )
-    if dev_traces:
-        fetched = [jax.tree.map(np.asarray, tr) for tr in dev_traces]
-        state["tr"] = {
+    if state.traces:
+        fetched = [jax.tree.map(np.asarray, tr) for tr in state.traces]
+        tree["tr"] = {
             "obj": np.stack([t.objective for t in fetched]),
             "primal": np.stack([t.primal_residual for t in fetched]),
             "dual": np.stack([t.dual_residual for t in fetched]),
             "cerr": np.stack([t.consensus_error for t in fetched]),
         }
-    path = checkpoint_path(directory, layer_next)
-    save_pytree(path, state)
+    path = checkpoint_path(directory, state.layer)
+    save_pytree(path, tree)
     return path
 
 
-def _load_checkpoint(path: str) -> dict:
-    """Flat checkpoint -> the resume state ``train_decentralized_ssfn``
-    restores from (inverse of ``_save_checkpoint``)."""
+def _load_checkpoint(path: str) -> _LoopState:
+    """Flat checkpoint -> the loop state it holds (inverse of
+    ``_save_checkpoint``); the features are not yet on a backend."""
     from repro.checkpoint.store import load_pytree_flat
 
     flat = load_pytree_flat(path)
-    layer_next = int(flat["layer_next"])
+    layer = int(flat["layer_next"])
     prev_cost = float(flat["prev_cost"])
-    traces = []
+    traces = ()
     if "tr/obj" in flat:
-        for i in range(flat["tr/obj"].shape[0]):
-            traces.append(admm_lib.ADMMTrace(
+        traces = tuple(
+            admm_lib.ADMMTrace(
                 flat["tr/obj"][i], flat["tr/primal"][i],
                 flat["tr/dual"][i], flat["tr/cerr"][i],
-            ))
-    r_list = None
+            )
+            for i in range(flat["tr/obj"].shape[0])
+        )
+    # Checkpoints written before the random matrices and the jitter
+    # history were stored have neither key: r stays None for the
+    # restorer to fill, and the jitter history restarts empty.
+    r = None
     if "r/0" in flat:
-        r_list = []
-        while f"r/{len(r_list)}" in flat:
-            r_list.append(jnp.asarray(flat[f"r/{len(r_list)}"]))
-    jitter_list = None
+        r = []
+        while f"r/{len(r)}" in flat:
+            r.append(jnp.asarray(flat[f"r/{len(r)}"]))
+        r = tuple(r)
+    jitter = ()
     if "jit" in flat:
-        jitter_list = [np.asarray(j) for j in flat["jit"]]
-    return {
-        "layer_next": layer_next,
-        "key": jnp.asarray(flat["key"]),
-        "y_workers": jnp.asarray(flat["y_workers"]),
-        "o_list": [
-            jnp.asarray(flat[f"o/{i}"]) for i in range(layer_next)
-        ],
-        "comm": int(flat["comm"]),
-        "prev_cost": None if np.isnan(prev_cost) else prev_cost,
-        "membership": flat["membership"],
-        "traces": traces,
-        # Pre-PR-7 checkpoints have neither key: r falls back to key
-        # derivation and the jitter history restarts empty.
-        "r_list": r_list,
-        "jitter_list": jitter_list,
-    }
+        jitter = tuple(np.asarray(j) for j in flat["jit"])
+    return _LoopState(
+        layer=layer,
+        key=jnp.asarray(flat["key"]),
+        o=tuple(jnp.asarray(flat[f"o/{i}"]) for i in range(layer)),
+        traces=traces,
+        jitter=jitter,
+        comm=int(flat["comm"]),
+        prev_cost=None if np.isnan(prev_cost) else prev_cost,
+        y_workers=jnp.asarray(flat["y_workers"]),
+        r=r,
+    )
 
 
 def _active_mask(policy: ConsensusPolicy, num_workers: int) -> np.ndarray:
@@ -196,8 +225,7 @@ class LayerwiseLog:
     wall_time_s: float
     comm_scalars: int                   # total scalars exchanged (eq. 15)
     #: (layers, M) guarded-Cholesky jitter level per layer solve (int32;
-    #: all-zero on a numerically healthy run).  Empty on the legacy
-    #: consensus_fn path.
+    #: all-zero on a numerically healthy run).
     jitter_levels: np.ndarray = field(
         default_factory=lambda: np.zeros((0, 0), np.int32)
     )
@@ -234,10 +262,8 @@ def train_decentralized_ssfn(
     cfg: ssfn_lib.SSFNConfig,
     key: jax.Array,
     *,
-    consensus_fn: Callable[[Array], Array] | None = None,
     backend: ConsensusBackend | None = None,
     policy: ConsensusPolicy | None = None,
-    gossip_rounds: int = 1,
     size_estimation_tol: float | None = None,
     trace_every: int = 1,
     checkpoint_dir: str | None = None,
@@ -262,12 +288,7 @@ def train_decentralized_ssfn(
         ``repro.core.topology.Topology``, ``QuantizedGossip``,
         ``LossyGossip``, ``StaleMixing``); defaults to the backend's
         policy.  Drives the eq.-15 communication accounting via its
-        M-aware ``exchanges_for``.
-    consensus_fn: legacy dense-H consensus primitive for the Z-update
-        (mutually exclusive with ``backend``/``policy``).
-    gossip_rounds: B, used only for the communication-load accounting when a
-        gossip consensus_fn is supplied (B=1 for exact all-reduce; gossip
-        backends account with their own ``num_rounds``).
+        ``comm_scalars``.
     size_estimation_tol: the SELF-SIZE-estimating behaviour (paper §I: "a
         decentralized estimation of the size of SSFN is possible"): stop
         growing layers once the relative cost improvement drops below this
@@ -309,17 +330,6 @@ def train_decentralized_ssfn(
     max_rollbacks: divergence-rollback budget; the run raises
         RuntimeError once a diverging layer has exhausted it.
     """
-    if consensus_fn is not None and (backend is not None or policy is not None):
-        raise ValueError("pass either consensus_fn or backend/policy, not both")
-    if consensus_fn is not None and (
-        checkpoint_dir is not None or resume or stop_after_layer is not None
-        or guard_divergence
-    ):
-        raise ValueError(
-            "checkpoint/resume and the divergence guard run through the "
-            "backend engine path; the legacy consensus_fn simulation does "
-            "not support them"
-        )
     if max_rollbacks < 0:
         raise ValueError(f"max_rollbacks must be >= 0, got {max_rollbacks}")
     if resume and checkpoint_dir is None:
@@ -331,87 +341,60 @@ def train_decentralized_ssfn(
             "size_estimation_tol reads the per-layer consensus objective; "
             "it cannot be combined with trace_every=0 (no traces)"
         )
-    if consensus_fn is not None:
-        if trace_every != 1:
-            raise ValueError(
-                "trace_every is a backend-path knob; the legacy "
-                "consensus_fn simulation always traces every iteration"
-            )
-        return _train_consensus_fn_path(
-            x_workers, t_workers, cfg, key,
-            consensus_fn=consensus_fn,
-            gossip_rounds=gossip_rounds,
-            size_estimation_tol=size_estimation_tol,
-        )
 
     q = cfg.num_classes
     t0 = time.perf_counter()
 
     engine_backend = backend or SimulatedBackend(x_workers.shape[0])
-    # eq.-15 accounting: the policy declares its own scalar count (its
-    # M-aware exchange schedule AND its communication interval — an
-    # ``AsyncGossip(interval=N)`` only touches the wire every N-th ADMM
-    # iteration); the implicit simulated-exact default (no backend, no
-    # policy) keeps the legacy ``gossip_rounds`` convention.
-    explicit = backend is not None or policy is not None
     policy = policy if policy is not None else engine_backend.policy
     num_workers = engine_backend.num_workers
     t_workers = engine_backend.shard_workers(t_workers)
-
-    o_list: list[Array] = []
-    w_next: Array | None = None
-    # Device-resident (K,) traces per layer; fetched once after the loop.
-    dev_traces: list[admm_lib.ADMMTrace] = []
-    jitter_list: list[np.ndarray] = []
-    comm = 0
-    prev_cost: float | None = None
-    layer_start = 0
     rollbacks = 0
 
-    restored = None
-    if resume:
-        ckpt = latest_checkpoint(checkpoint_dir)
-        if ckpt is not None:
-            restored = _load_checkpoint(ckpt)
-    if restored is not None:
-        layer_start = restored["layer_next"]
-        key = restored["key"]
-        o_list = list(restored["o_list"])
-        dev_traces = list(restored["traces"])
-        jitter_list = list(restored["jitter_list"] or [])
-        comm = restored["comm"]
-        prev_cost = restored["prev_cost"]
-        y_workers = engine_backend.shard_workers(restored["y_workers"])
-        r_list = (
-            list(restored["r_list"])
-            if restored["r_list"] is not None
-            else list(ssfn_lib.init_random_matrices(key, cfg))
+    def restore(default: _LoopState | None, r: tuple | None = None):
+        """Resume and rollback both restart here: the latest complete
+        checkpoint's state with its features on the backend, or
+        ``default`` when there is none.  A checkpoint without random
+        matrices takes ``r`` (the live draw, on rollback) or re-derives
+        them from its own key."""
+        path = (
+            latest_checkpoint(checkpoint_dir)
+            if checkpoint_dir is not None else None
         )
-        if layer_start <= cfg.num_layers:
-            w_next = ssfn_lib.build_weight(
-                o_list[-1], r_list[layer_start - 1], q
-            )
-    else:
-        r_list = list(ssfn_lib.init_random_matrices(key, cfg))
-        y_workers = engine_backend.shard_workers(x_workers)   # y_0 = x
+        if path is None:
+            return default
+        saved = _load_checkpoint(path)
+        if saved.r is None:
+            if r is None:
+                r = tuple(ssfn_lib.init_random_matrices(saved.key, cfg))
+            saved = saved._replace(r=r)
+        return saved._replace(
+            y_workers=engine_backend.shard_workers(saved.y_workers)
+        )
 
     # The divergence guard's restart point before the first checkpoint
     # exists (references only — none of these buffers is ever donated:
     # donation starts at layer 2 with engine-materialized carries).
-    entry_state = (
-        layer_start, key, list(o_list), list(dev_traces), list(jitter_list),
-        comm, prev_cost, y_workers, w_next, list(r_list),
-    )
+    entry = restore(None) if resume else None
+    if entry is None:
+        entry = _LoopState(
+            layer=0,
+            key=key,
+            y_workers=engine_backend.shard_workers(x_workers),   # y_0 = x
+            r=tuple(ssfn_lib.init_random_matrices(key, cfg)),
+        )
 
-    layer = layer_start
-    while layer <= cfg.num_layers:
+    state = entry
+    while state.layer <= cfg.num_layers:
+        layer = state.layer
         with profiling.span(profiling.LAYER, layer=layer):
+            w = _layer_weight(state, q)
             with profiling.span(profiling.DISPATCH):
                 step = engine_lib.fused_layer_step(
                     engine_backend,
-                    y_workers,
+                    state.y_workers,
                     t_workers,
-                    w_next,
+                    w,
                     mu=_mu_for_layer(cfg, layer),
                     eps_radius=cfg.eps_radius,
                     num_iters=cfg.admm_iters,
@@ -428,7 +411,7 @@ def train_decentralized_ssfn(
             diverged = False
             if guard_divergence:
                 with profiling.span(profiling.HOST_SYNC):
-                    diverged = _step_diverged(step, prev_cost)
+                    diverged = _step_diverged(step, state.prev_cost)
             if diverged:
                 if rollbacks >= max_rollbacks:
                     raise RuntimeError(
@@ -436,33 +419,9 @@ def train_decentralized_ssfn(
                         f"(max_rollbacks={max_rollbacks}) is spent"
                     )
                 rollbacks += 1
-                ckpt = (
-                    latest_checkpoint(checkpoint_dir)
-                    if checkpoint_dir is not None else None
-                )
-                if ckpt is not None:
-                    restored = _load_checkpoint(ckpt)
-                    layer = restored["layer_next"]
-                    key = restored["key"]
-                    o_list = list(restored["o_list"])
-                    dev_traces = list(restored["traces"])
-                    jitter_list = list(restored["jitter_list"] or [])
-                    comm = restored["comm"]
-                    prev_cost = restored["prev_cost"]
-                    y_workers = engine_backend.shard_workers(
-                        restored["y_workers"]
-                    )
-                    if restored["r_list"] is not None:
-                        r_list = list(restored["r_list"])
-                else:
-                    (layer, key, o_list, dev_traces, jitter_list, comm,
-                     prev_cost, y_workers, w_next, r_list) = entry_state
-                    o_list = list(o_list)
-                    dev_traces = list(dev_traces)
-                    jitter_list = list(jitter_list)
-                    r_list = list(r_list)
+                state = restore(entry, r=state.r)
                 warnings.warn(
-                    f"layer solve diverged; rolling back to layer {layer} "
+                    f"layer solve diverged; rolling back to layer {state.layer} "
                     f"with a perturbed key (rollback {rollbacks}/"
                     f"{max_rollbacks})",
                     RuntimeWarning,
@@ -470,54 +429,50 @@ def train_decentralized_ssfn(
                 )
                 # Perturb the key and re-draw every random matrix the
                 # restart point has not consumed.  r[layer-1] only feeds the
-                # NEXT propagation (it rebuilds w_next below), so it is
-                # still free to change; r[0..layer-2] shaped the restored
-                # features and must stay verbatim.
-                key = jax.random.fold_in(key, 7 + rollbacks)
+                # NEXT propagation, so it is still free to change;
+                # r[0..layer-2] shaped the restored features and must stay
+                # verbatim.
+                key = jax.random.fold_in(state.key, 7 + rollbacks)
                 fresh = ssfn_lib.init_random_matrices(key, cfg)
-                first_free = max(layer - 1, 0)
-                r_list[first_free:] = list(fresh[first_free:])
-                if layer == 0:
-                    w_next = None
-                elif layer <= cfg.num_layers:
-                    with profiling.span(profiling.BUILD_WEIGHT):
-                        w_next = ssfn_lib.build_weight(
-                            o_list[-1], r_list[layer - 1], q
-                        )
+                first_free = max(state.layer - 1, 0)
+                state = state._replace(
+                    key=key,
+                    r=state.r[:first_free] + tuple(fresh[first_free:]),
+                )
                 continue
 
-            y_workers = step.y_workers
-            o_list.append(step.o_star)
-            if step.trace is not None:
-                dev_traces.append(step.trace)
+            jitter = state.jitter
             if step.jitter is not None:
                 with profiling.span(profiling.HOST_SYNC):
-                    jitter_list.append(np.asarray(jax.device_get(step.jitter)))
-            # Communication accounting, eq. 15: Q * n_{l-1} scalars per
-            # exchange, B exchanges per consensus, K communicating consensus
-            # rounds per layer — the policy itself knows its exchange count
-            # and how many of the K iterations actually hit the wire.
-            if explicit:
-                comm += policy.comm_scalars(
-                    scalars=q * y_workers.shape[1],
+                    jitter += (np.asarray(jax.device_get(step.jitter)),)
+            state = state._replace(
+                layer=layer + 1,
+                o=state.o + (step.o_star,),
+                traces=state.traces + (
+                    (step.trace,) if step.trace is not None else ()
+                ),
+                jitter=jitter,
+                # Communication accounting, eq. 15: Q * n_{l-1} scalars per
+                # exchange, B exchanges per consensus, K communicating
+                # consensus rounds per layer — the policy itself knows its
+                # exchange count and how many of the K iterations actually
+                # hit the wire.
+                comm=state.comm + policy.comm_scalars(
+                    scalars=q * step.y_workers.shape[1],
                     num_consensus=cfg.admm_iters,
                     num_workers=num_workers,
-                )
-            else:
-                comm += q * y_workers.shape[1] * gossip_rounds * cfg.admm_iters
+                ),
+                y_workers=step.y_workers,
+            )
 
             stopping = stop_after_layer is not None and layer >= stop_after_layer
             if checkpoint_dir is not None and (
-                stopping or (layer + 1) % checkpoint_every == 0
+                stopping or state.layer % checkpoint_every == 0
             ):
                 with profiling.span(profiling.HOST_SYNC):
                     _save_checkpoint(
-                        checkpoint_dir, layer_next=layer + 1, key=key,
-                        y_workers=np.asarray(jax.device_get(y_workers)),
-                        o_list=o_list, step=step, dev_traces=dev_traces,
-                        comm=comm, prev_cost=prev_cost,
+                        checkpoint_dir, state, step=step,
                         active_mask=_active_mask(policy, num_workers),
-                        r_list=r_list, jitter_list=jitter_list,
                     )
             if stopping:
                 break
@@ -529,37 +484,35 @@ def train_decentralized_ssfn(
             if size_estimation_tol is not None:
                 with profiling.span(profiling.HOST_SYNC):
                     cur = float(step.trace.objective[-1])
+                prev_cost = state.prev_cost
                 if (
                     prev_cost is not None
                     and prev_cost - cur < size_estimation_tol * max(prev_cost, 1e-12)
                 ):
                     break
-                prev_cost = cur
+                state = state._replace(prev_cost=cur)
             elif guard_divergence and step.trace is not None:
                 # Track the layer cost so the guard's blow-up check has a
                 # reference even without size estimation.
                 with profiling.span(profiling.HOST_SYNC):
-                    prev_cost = float(step.trace.objective[-1])
-
-            if layer < cfg.num_layers:
-                with profiling.span(profiling.BUILD_WEIGHT):
-                    w_next = ssfn_lib.build_weight(step.o_star, r_list[layer], q)
-            layer += 1
+                    state = state._replace(
+                        prev_cost=float(step.trace.objective[-1])
+                    )
 
     # One bulk fetch of every per-layer trace after the loop.  The
     # collective-free hot path (trace_every=0) has none: the log carries
     # empty (L+1, 0) trace arrays and no layer costs.
-    traces = [jax.tree.map(np.asarray, tr) for tr in dev_traces]
+    traces = [jax.tree.map(np.asarray, tr) for tr in state.traces]
     layer_costs = [float(tr.objective[-1]) for tr in traces]
 
     def stacked(field: str) -> np.ndarray:
         if not traces:
-            return np.zeros((len(o_list), 0), np.float32)
+            return np.zeros((len(state.o), 0), np.float32)
         return np.stack([getattr(tr, field) for tr in traces])
 
     # Early size-estimation stop leaves fewer readouts than random matrices.
     params = ssfn_lib.SSFNParams(
-        o=tuple(o_list), r=tuple(r_list[: len(o_list) - 1])
+        o=state.o, r=tuple(state.r[: len(state.o) - 1])
     )
     log = LayerwiseLog(
         layer_costs=layer_costs,
@@ -568,76 +521,12 @@ def train_decentralized_ssfn(
         admm_dual=stacked("dual_residual"),
         consensus_error=stacked("consensus_error"),
         wall_time_s=time.perf_counter() - t0,
-        comm_scalars=comm,
+        comm_scalars=state.comm,
         jitter_levels=(
-            np.stack(jitter_list)
-            if jitter_list else np.zeros((0, 0), np.int32)
+            np.stack(state.jitter)
+            if state.jitter else np.zeros((0, 0), np.int32)
         ),
         rollbacks=rollbacks,
-    )
-    return params, log
-
-
-def _train_consensus_fn_path(
-    x_workers: Array,
-    t_workers: Array,
-    cfg: ssfn_lib.SSFNConfig,
-    key: jax.Array,
-    *,
-    consensus_fn: Callable[[Array], Array],
-    gossip_rounds: int,
-    size_estimation_tol: float | None,
-) -> tuple[ssfn_lib.SSFNParams, LayerwiseLog]:
-    """Legacy batched dense-H simulation (arbitrary mixing matrix H)."""
-    q = cfg.num_classes
-    t0 = time.perf_counter()
-    r_list = ssfn_lib.init_random_matrices(key, cfg)
-
-    o_list: list[Array] = []
-    y_workers = x_workers                      # y_0 = x
-    layer_costs: list[float] = []
-    traces = {"obj": [], "primal": [], "dual": [], "cerr": []}
-    comm = 0
-
-    for layer in range(cfg.num_layers + 1):
-        res = admm_lib.admm_ridge_consensus(
-            y_workers,
-            t_workers,
-            mu=_mu_for_layer(cfg, layer),
-            eps_radius=cfg.eps_radius,
-            num_iters=cfg.admm_iters,
-            consensus_fn=consensus_fn,
-        )
-        o_l = res.o_star
-        o_list.append(o_l)
-        layer_costs.append(float(res.trace.objective[-1]))
-        traces["obj"].append(np.asarray(res.trace.objective))
-        traces["primal"].append(np.asarray(res.trace.primal_residual))
-        traces["dual"].append(np.asarray(res.trace.dual_residual))
-        traces["cerr"].append(np.asarray(res.trace.consensus_error))
-        comm += q * y_workers.shape[1] * gossip_rounds * cfg.admm_iters
-
-        if (
-            size_estimation_tol is not None
-            and len(layer_costs) >= 2
-            and layer_costs[-2] - layer_costs[-1]
-            < size_estimation_tol * max(layer_costs[-2], 1e-12)
-        ):
-            break
-
-        if layer < cfg.num_layers:
-            w_next = ssfn_lib.build_weight(o_l, r_list[layer], q)
-            y_workers = jax.vmap(lambda ym: jax.nn.relu(w_next @ ym))(y_workers)
-
-    params = ssfn_lib.SSFNParams(o=tuple(o_list), r=r_list[: len(o_list) - 1])
-    log = LayerwiseLog(
-        layer_costs=layer_costs,
-        admm_objective=np.stack(traces["obj"]),
-        admm_primal=np.stack(traces["primal"]),
-        admm_dual=np.stack(traces["dual"]),
-        consensus_error=np.stack(traces["cerr"]),
-        wall_time_s=time.perf_counter() - t0,
-        comm_scalars=comm,
     )
     return params, log
 

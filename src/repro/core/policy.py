@@ -92,8 +92,8 @@ import numpy as np
 
 from repro.core import consensus as consensus_lib
 from repro.core import topology as topology_lib
-from repro.core.consensus import (  # noqa: F401  (canonical re-exports,
-    quantize_nearest,                # absorbed from the core.robust shim)
+from repro.core.consensus import (  # noqa: F401  (canonical re-exports)
+    quantize_nearest,
     quantize_stochastic,
 )
 from repro.core.topology import Ring, Topology, parse_topology

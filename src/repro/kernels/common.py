@@ -5,6 +5,12 @@ import jax
 from jax.custom_batching import custom_vmap
 
 
+def aligned(*dims: int) -> bool:
+    """True when every dimension is a whole number of 128-wide tiles:
+    the one shape rule that decides whether a Pallas kernel runs."""
+    return all(d % 128 == 0 for d in dims)
+
+
 def default_interpret() -> bool:
     """Pallas TPU kernels run in interpret mode everywhere but real TPU."""
     return jax.default_backend() != "tpu"

@@ -73,10 +73,6 @@ def _tokens(bucket: tuple[int, int]) -> int:
     return bucket[0] * bucket[1]
 
 
-def _aligned(*dims: int) -> bool:
-    return all(d % 128 == 0 for d in dims)
-
-
 class ServeEngine:
     """Serve a trained dSSFN stack with compile-once batched inference.
 
@@ -358,10 +354,13 @@ class ServeEngine:
         }
 
     def _propagate(self, w: Array, y: Array) -> Array:
-        if self.use_kernels and _aligned(w.shape[0], w.shape[1], y.shape[1]):
-            from repro.kernels.matmul_relu import matmul_relu
+        if self.use_kernels:
+            from repro.kernels.common import aligned
 
-            return matmul_relu(w, y).astype(y.dtype)
+            if aligned(*w.shape, y.shape[1]):
+                from repro.kernels.matmul_relu import matmul_relu
+
+                return matmul_relu(w, y).astype(y.dtype)
         return jax.nn.relu(w @ y)
 
     def _apply_features(self, feat_params, x):

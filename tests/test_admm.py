@@ -6,9 +6,9 @@ import pytest
 from jax.extend import core as jex_core
 from repro.testing import given, settings, st
 
-from repro.core import admm, consensus, topology
+from repro.core import admm, topology
 from repro.core.backend import SimulatedBackend
-from repro.core.policy import parse_policy
+from repro.core.policy import Gossip, parse_policy
 
 
 def _problem(key, n, q, j, m):
@@ -47,9 +47,9 @@ def test_gossip_consensus_preserves_equivalence():
     eps = 6.0
     h = topology.circular_mixing_matrix(8, 2)
     rounds = topology.gossip_rounds_for_tolerance(h, 1e-9)
-    cfn = consensus.make_consensus_fn("gossip", h=h, num_rounds=rounds)
     dec = admm.admm_ridge_consensus(
-        yw, tw, mu=1e-2, eps_radius=eps, num_iters=200, consensus_fn=cfn
+        yw, tw, mu=1e-2, eps_radius=eps, num_iters=200,
+        policy=Gossip(rounds=rounds, topology=topology.Ring(2)),
     )
     oracle = admm.exact_constrained_ridge(y, t, eps_radius=eps)
     rel = float(jnp.linalg.norm(dec.o_star - oracle) / jnp.linalg.norm(oracle))

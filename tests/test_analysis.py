@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro import analysis, dssfn
-from repro.core import admm
+from repro.core import admm, engine
 from repro.core import policy as policy_lib
 from repro.core.backend import SimulatedBackend
 from repro.core.policy import (
@@ -247,6 +247,26 @@ def test_expected_mix_collectives_model():
     assert analysis.expected_mix_collectives(stale, M) == {
         "collective-permute": hops
     }
+
+
+def test_wire_probe_is_the_engine_layer_program():
+    """The wire lint checks the program training runs: hot_program_texts
+    lowers the engine's trace_every=0 layer program, through the same
+    executable-cache entry."""
+    backend = SimulatedBackend(4)
+    policy = Gossip(rounds=2, topology=Ring(1))
+    texts = analysis.hot_program_texts(backend, policy, num_iters=8)
+    assert backend.lowerings == 1, backend.cache_info()
+    program = engine.layer_program(
+        backend,
+        jax.ShapeDtypeStruct((4, 16, 8), jnp.float32),
+        jax.ShapeDtypeStruct((4, 3, 8), jnp.float32),
+        None, mu=1e-2, eps_radius=6.0, num_iters=8, policy=policy,
+        trace_every=0,
+    )
+    assert program.lowering_texts() == texts
+    assert backend.lowerings == 1, backend.cache_info()
+    assert backend.cache_hits == 1
 
 
 def test_probe_iters_rounds_to_interval():

@@ -150,13 +150,6 @@ def test_layerwise_gossip_backend_comm_accounting():
 # Error paths
 # ------------------------------------------------------------------
 
-def test_make_consensus_fn_error_paths():
-    with pytest.raises(ValueError, match="unknown consensus mode"):
-        consensus.make_consensus_fn("bogus")
-    with pytest.raises(ValueError, match="mixing matrix"):
-        consensus.make_consensus_fn("gossip")
-
-
 def test_make_backend_error_paths():
     with pytest.raises(ValueError, match="unknown backend kind"):
         make_backend("tpu-pod")
@@ -185,17 +178,6 @@ def test_mismatched_worker_count_rejected():
         admm.admm_ridge_consensus(
             yw, tw, mu=1e-2, eps_radius=6.0, num_iters=5,
             backend=SimulatedBackend(8),
-        )
-
-
-def test_consensus_fn_and_backend_mutually_exclusive():
-    _, _, yw, tw = _problem(jax.random.PRNGKey(9), 16, 3, 160, 4)
-    h = topology.circular_mixing_matrix(4, 1)
-    cfn = consensus.make_consensus_fn("gossip", h=h, num_rounds=2)
-    with pytest.raises(ValueError, match="not both"):
-        admm.admm_ridge_consensus(
-            yw, tw, mu=1e-2, eps_radius=6.0, num_iters=5,
-            consensus_fn=cfn, backend=SimulatedBackend(4),
         )
 
 

@@ -406,6 +406,72 @@ def test_checkpoint_roundtrips_random_matrices(tmp_path):
         assert np.array_equal(flat[f"r/{i}"], np.asarray(r))
 
 
+@pytest.mark.parametrize("drill", ["resume", "rollback"])
+def test_checkpoint_without_stored_draws_restores(
+    tmp_path, monkeypatch, drill
+):
+    """A checkpoint written before the random matrices and the jitter
+    history were stored (no ``r/*``, no ``jit``) goes through the same
+    restore.  Resume re-derives the draw from the checkpoint's key and
+    matches the uninterrupted run bit for bit; a rollback onto it keeps
+    the live draw, completes, and keeps the checkpointed layers'
+    readouts verbatim."""
+    xw, tw = _data(jax.random.PRNGKey(3))
+    key = jax.random.PRNGKey(7)
+    base = dict(cfg=_cfg(), backend="simulated", workers=4)
+    full = dssfn.train(dssfn.TrainSpec(**base), xw, tw, key)
+
+    ckpt = os.path.join(tmp_path, "ckpt")
+    dssfn.train(
+        dssfn.TrainSpec(**base, checkpoint_dir=ckpt, stop_after_layer=1),
+        xw, tw, key,
+    )
+    path = latest_checkpoint(ckpt)
+    flat = load_pytree_flat(path)
+    assert "r/0" in flat and "jit" in flat
+    save_pytree(path, {
+        k: v for k, v in flat.items() if not (k.startswith("r/") or k == "jit")
+    })
+    stripped = load_pytree_flat(path)
+    assert "r/0" not in stripped and "jit" not in stripped
+
+    resume = dict(**base, checkpoint_dir=ckpt, resume=True)
+    if drill == "resume":
+        resumed = dssfn.train(dssfn.TrainSpec(**resume), xw, tw, key)
+        _assert_same_run(full, resumed)
+        # The jitter history restarts at the resumed layer.
+        assert resumed.log.jitter_levels.shape[0] == len(full.params.o) - 2
+        return
+
+    real = layerwise._step_diverged
+    calls = {"n": 0}
+
+    def fake(step, prev_cost, blowup=1e3):
+        calls["n"] += 1
+        if calls["n"] == 1:       # the first resumed layer, before it saves
+            return True
+        return real(step, prev_cost, blowup)
+
+    monkeypatch.setattr(layerwise, "_step_diverged", fake)
+    with pytest.warns(RuntimeWarning, match="rolling back to layer 2"):
+        healed = dssfn.train(
+            dssfn.TrainSpec(**resume, guard_divergence=True), xw, tw, key,
+        )
+    assert healed.log.rollbacks == 1
+    assert len(healed.params.o) == len(full.params.o)
+    for a, b in zip(full.params.o[:2], healed.params.o[:2]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # r[0] shaped the checkpointed features; r[1] was free and re-drawn.
+    assert np.array_equal(
+        np.asarray(full.params.r[0]), np.asarray(healed.params.r[0])
+    )
+    assert not np.array_equal(
+        np.asarray(full.params.r[1]), np.asarray(healed.params.r[1])
+    )
+    for o in healed.params.o:
+        assert bool(np.all(np.isfinite(np.asarray(o))))
+
+
 # ------------------------------------------------------------------
 # Divergence guard: rollback, key perturbation, budget exhaustion
 # ------------------------------------------------------------------
@@ -546,10 +612,4 @@ def test_checkpoint_validation_errors():
     with pytest.raises(ValueError, match="checkpoint_every"):
         layerwise.train_decentralized_ssfn(
             xw, tw, cfg, key, checkpoint_dir="/tmp/x", checkpoint_every=0
-        )
-    with pytest.raises(ValueError, match="consensus_fn"):
-        layerwise.train_decentralized_ssfn(
-            xw, tw, cfg, key,
-            consensus_fn=lambda z: z,
-            checkpoint_dir="/tmp/x",
         )

@@ -275,29 +275,55 @@ def test_use_kernels_misaligned_shapes_fall_back():
 # Device-resident traces / size estimation through the engine
 # ------------------------------------------------------------------
 
-def test_engine_log_matches_legacy_consensus_fn_path():
-    """Engine traces (device-accumulated, fetched once) == the legacy
-    batched dense-H loop's traces for the equivalent exact consensus."""
+def test_engine_log_matches_plain_objective():
+    """Engine traces (device-accumulated, fetched once) are right: each
+    layer's final traced objective equals sum_m ||T_m - O_l Y_{l,m}||^2
+    recomputed in plain float64 from the returned params, with Y
+    propagated by plain relu(W @ Y)."""
     import numpy as np
-
-    from repro.core import consensus
 
     m = 4
     cfg, xw, tw, kinit = _train_problem(
         jax.random.PRNGKey(11), m=m, p=8, q=3, jm=16, num_layers=1, hidden=20,
         admm_iters=20,
     )
-    cfn = consensus.make_consensus_fn("exact")
-    p_legacy, log_legacy = layerwise.train_decentralized_ssfn(
-        xw, tw, cfg, kinit, consensus_fn=cfn
-    )
-    p_engine, log_engine = layerwise.train_decentralized_ssfn(xw, tw, cfg, kinit)
-    for a, b in zip(p_legacy.o, p_engine.o):
-        assert jnp.allclose(a, b, atol=1e-5)
-    np.testing.assert_allclose(
-        log_legacy.admm_objective, log_engine.admm_objective, rtol=1e-5
-    )
-    assert log_legacy.admm_objective.shape == log_engine.admm_objective.shape
+    params, log = layerwise.train_decentralized_ssfn(xw, tw, cfg, kinit)
+    layers = cfg.num_layers + 1
+    for trace in (log.admm_objective, log.admm_primal, log.admm_dual,
+                  log.consensus_error):
+        assert trace.shape == (layers, cfg.admm_iters)
+    assert len(log.layer_costs) == layers
+
+    t = np.asarray(tw, np.float64)
+    y = np.asarray(xw, np.float64)
+    for layer in range(layers):
+        if layer > 0:
+            w = np.asarray(ssfn.build_weight(
+                params.o[layer - 1], params.r[layer - 1], cfg.num_classes
+            ), np.float64)
+            y = np.maximum(np.einsum("ij,mjk->mik", w, y), 0.0)
+        o = np.asarray(params.o[layer], np.float64)
+        want = float(np.sum((t - np.einsum("qi,mik->mqk", o, y)) ** 2))
+        np.testing.assert_allclose(log.admm_objective[layer, -1], want, rtol=1e-5)
+        assert log.layer_costs[layer] == log.admm_objective[layer, -1]
+
+
+def test_standalone_solve_shares_the_layer_zero_program():
+    """admm_ridge_consensus runs the engine's l=0 program: after a
+    fused_layer_step(w=None) with the same hyper-parameters and shapes it
+    adds no lowering and returns the bit-identical readout."""
+    m = 4
+    _, _, yw, tw = _problem(jax.random.PRNGKey(13), 16, 3, 160, m)
+    backend = SimulatedBackend(m)
+    kw = dict(mu=1e-2, eps_radius=6.0, num_iters=20)
+    step = engine.fused_layer_step(backend, yw, tw, None, **kw)
+    assert backend.lowerings == 1, backend.cache_info()
+    res = admm.admm_ridge_consensus(yw, tw, backend=backend, **kw)
+    assert backend.lowerings == 1, backend.cache_info()
+    assert backend.cache_hits == 1
+    assert jnp.array_equal(res.o_star, step.o_star)
+    assert jnp.array_equal(res.o_workers, step.o_workers)
+    assert jnp.array_equal(res.jitter, step.jitter)
 
 
 def test_size_estimation_through_engine():

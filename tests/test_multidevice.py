@@ -263,7 +263,7 @@ def test_compressed_gossip_and_hot_path_on_8_devices():
       asserted via the backend lowering stats / HLO collective counts.
     """
     out = run_subprocess("""
-    from repro.core import admm
+    from repro.core import admm, engine
     from repro.core.backend import MeshBackend
     from repro.core.policy import ExactMean, Gossip, RingGossip
     from repro.core.topology import Ring, Torus
@@ -296,17 +296,11 @@ def test_compressed_gossip_and_hot_path_on_8_devices():
     from repro import analysis
 
     K = 10
-    z0 = jnp.zeros((q, n))
     def probe(policy, trace_every):
-        backend = MeshBackend(wmesh, policy=policy)
-        def worker(y_m, t_m, z0r):
-            a, chol, _ = admm._worker_stats_local(y_m, t_m, 1e-2, False)
-            return admm.worker_admm_iterations(
-                backend, a, chol, y_m, t_m, z0r, mu=1e-2, eps_radius=6.0,
-                num_iters=K, policy=policy, trace_every=trace_every)
-        return backend.lowering_stats(
-            worker, yw, tw, replicated=(z0,),
-            key=("probe", trace_every), policy=policy)
+        return engine.layer_program(
+            MeshBackend(wmesh, policy=policy), yw, tw, None, mu=1e-2,
+            eps_radius=6.0, num_iters=K, policy=policy,
+            trace_every=trace_every).lowering_stats()
 
     def expect_hot(policy):
         per_mix = analysis.expected_mix_collectives(policy, m)
@@ -408,7 +402,7 @@ def test_async_faults_and_elastic_resume_on_8_devices():
     """
     out = run_subprocess("""
     import tempfile
-    from repro.core import admm, layerwise, ssfn
+    from repro.core import admm, engine, layerwise, ssfn
     from repro.core.backend import MeshBackend, SimulatedBackend
     from repro.core.policy import AsyncGossip, FaultModel, Gossip
     from repro.core.topology import Hypercube
@@ -425,16 +419,11 @@ def test_async_faults_and_elastic_resume_on_8_devices():
     # 1) Null fault model == serial Gossip: identical collectives in the
     # lowered hot path, bit-identical solve.
     K = 10
-    z0 = jnp.zeros((q, n))
     def probe(policy):
-        backend = MeshBackend(wmesh, policy=policy)
-        def worker(y_m, t_m, z0r):
-            a, chol, _ = admm._worker_stats_local(y_m, t_m, 1e-2, False)
-            return admm.worker_admm_iterations(
-                backend, a, chol, y_m, t_m, z0r, mu=1e-2, eps_radius=6.0,
-                num_iters=K, policy=policy, trace_every=0)
-        return backend.lowering_stats(
-            worker, yw, tw, replicated=(z0,), key="probe", policy=policy)
+        return engine.layer_program(
+            MeshBackend(wmesh, policy=policy), yw, tw, None, mu=1e-2,
+            eps_radius=6.0, num_iters=K, policy=policy,
+            trace_every=0).lowering_stats()
 
     anull = AsyncGossip(rounds=3, topology=Hypercube())
     gser = Gossip(rounds=3, topology=Hypercube(), compress=False)

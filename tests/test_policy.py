@@ -148,13 +148,6 @@ def test_mode_string_alias_is_removed():
         SimulatedBackend(4, flavor="exact")
 
 
-def test_make_consensus_fn_is_deprecated_alias():
-    with pytest.warns(DeprecationWarning, match="make_consensus_fn is deprecated"):
-        fn = consensus.make_consensus_fn("exact")
-    x = jnp.arange(12.0).reshape(4, 3)
-    assert jnp.allclose(fn(x), jnp.broadcast_to(x.mean(0), x.shape))
-
-
 def test_default_backend_has_exact_policy_without_warning():
     with warnings.catch_warnings():
         warnings.simplefilter("error", DeprecationWarning)

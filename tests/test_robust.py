@@ -4,9 +4,6 @@ consensus), running through the same backend + compile-once engine as
 the ideal-network path.  Includes the centralized-proximity guarantees:
 each policy's final solution stays within a stated tolerance of the
 exact-consensus run on the synthetic task."""
-import importlib
-import sys
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +17,6 @@ from repro.core.policy import (
     QuantizedGossip,
     RingGossip,
     StaleMixing,
-    quantize_stochastic,
 )
 
 
@@ -35,24 +31,6 @@ def _problem(key, n=16, q=3, j=160, m=8):
 
 def _rel_to_oracle(res, oracle):
     return float(jnp.linalg.norm(res.o_star - oracle) / jnp.linalg.norm(oracle))
-
-
-def test_robust_module_is_deprecated_shim():
-    """core/robust.py warns on import and re-exports the canonical
-    policy-module names — repro.core.policy is the API."""
-    sys.modules.pop("repro.core.robust", None)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        robust = importlib.import_module("repro.core.robust")
-    assert any(
-        issubclass(w.category, DeprecationWarning)
-        and "repro.core.policy" in str(w.message)
-        for w in caught
-    )
-    assert robust.QuantizedGossip is QuantizedGossip
-    assert robust.LossyGossip is LossyGossip
-    assert robust.StaleMixing is StaleMixing
-    assert robust.quantize_stochastic is quantize_stochastic
 
 
 # --------------------------------------------------------- stale (async)

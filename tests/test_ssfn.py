@@ -4,6 +4,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import equivalence, layerwise, ssfn
+from repro.core.policy import Gossip
+from repro.core.topology import Ring
 from repro.data import make_classification, partition_workers
 
 
@@ -120,11 +122,16 @@ def test_self_size_estimation(dataset, cfg):
 
 
 def test_comm_accounting(dataset, cfg):
-    """eq. (15): total scalars = sum_l Q * n_{l-1} * B * K."""
-    xw, tw = partition_workers(dataset.x_train, dataset.t_train, 4)
+    """eq. (15): total scalars = sum_l Q * n_{l-1} * B * K, with B the
+    policy's exchanges per consensus."""
+    m = 4
+    xw, tw = partition_workers(dataset.x_train, dataset.t_train, m)
+    policy = Gossip(rounds=3, topology=Ring(1))
     _, log = layerwise.train_decentralized_ssfn(
-        xw, tw, cfg, jax.random.PRNGKey(0), gossip_rounds=3
+        xw, tw, cfg, jax.random.PRNGKey(0), policy=policy
     )
     q, n, k = cfg.num_classes, cfg.n, cfg.admm_iters
-    expected = (q * cfg.input_dim + cfg.num_layers * q * n) * 3 * k
+    b = policy.exchanges_for(m)
+    assert b == 3 * 2          # 3 rounds over 2 ring neighbours
+    expected = (q * cfg.input_dim + cfg.num_layers * q * n) * b * k
     assert log.comm_scalars == expected

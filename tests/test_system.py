@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import consensus, equivalence, layerwise, ssfn, topology
+from repro.core import equivalence, layerwise, ssfn, topology
+from repro.core.policy import Gossip
 from repro.data import make_classification, paper_dataset, partition_workers
 
 
@@ -34,9 +35,9 @@ def test_e2e_dssfn_over_circular_network(setup):
     xw, tw = partition_workers(data.x_train, data.t_train, m)
     h = topology.circular_mixing_matrix(m, 2)
     rounds = topology.gossip_rounds_for_tolerance(h, 1e-9)
-    cfn = consensus.make_consensus_fn("gossip", h=h, num_rounds=rounds)
     params_d, log_d = layerwise.train_decentralized_ssfn(
-        xw, tw, cfg, key, consensus_fn=cfn, gossip_rounds=rounds
+        xw, tw, cfg, key,
+        policy=Gossip(rounds=rounds, topology=topology.Ring(2)), trace_every=1,
     )
     params_c, _ = layerwise.train_centralized_ssfn(
         data.x_train, data.t_train, cfg, key
@@ -71,10 +72,9 @@ def test_insufficient_gossip_breaks_equivalence(setup):
     data, cfg = setup
     m = 8
     xw, tw = partition_workers(data.x_train, data.t_train, m)
-    h = topology.circular_mixing_matrix(m, 1)
-    cfn = consensus.make_consensus_fn("gossip", h=h, num_rounds=1)
     _, log = layerwise.train_decentralized_ssfn(
-        xw, tw, cfg, jax.random.PRNGKey(11), consensus_fn=cfn, gossip_rounds=1
+        xw, tw, cfg, jax.random.PRNGKey(11),
+        policy=Gossip(rounds=1, topology=topology.Ring(1)), trace_every=1,
     )
     err = np.asarray(log.consensus_error)
     # Either the workers visibly disagree or training degenerates (NaN) —

@@ -96,18 +96,6 @@ def test_trace_every_validation():
         admm.admm_ridge_consensus(yw, tw, trace_every=3, **kw)
     with pytest.raises(ValueError, match=">= 0"):
         admm.admm_ridge_consensus(yw, tw, trace_every=-1, **kw)
-    # The legacy dense-H simulation path has no trace gate.
-    import repro.core.consensus as consensus
-    import warnings
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        fn = consensus.make_consensus_fn("exact")
-    with pytest.raises(ValueError, match="consensus_fn"):
-        admm.admm_ridge_consensus(
-            yw, tw, mu=1e-2, eps_radius=6.0, num_iters=20,
-            consensus_fn=fn, trace_every=0,
-        )
 
 
 def test_fused_layer_step_trace_every_zero():
