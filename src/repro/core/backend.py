@@ -82,6 +82,15 @@ def _supports_donation() -> bool:
     return jax.default_backend() != "cpu"
 
 
+def _put(x, sharding):
+    """``jax.device_put`` that returns an array already in ``sharding``
+    as it is: the layer loop hands the same placed operands to every
+    layer program, and a put that moves nothing still costs the host."""
+    if getattr(x, "sharding", None) == sharding:
+        return x
+    return jax.device_put(x, sharding)
+
+
 def _reject_legacy_kwargs(name: str, kwargs: dict) -> None:
     """The PR-3 ``mode=`` string aliases are gone: fail with a migration
     hint (a clean ``TypeError``, the unknown-keyword contract) instead of
@@ -372,6 +381,12 @@ class ConsensusBackend(abc.ABC):
             "keys": [repr(k) for k in self._exec_cache],
         }
 
+    @property
+    def replicated_sharding(self) -> NamedSharding | None:
+        """Where an operand every worker sees whole lives: None here (the
+        default device), the mesh-replicated sharding on a mesh."""
+        return None
+
     def _place_replicated(self, replicated: tuple) -> tuple:
         return replicated
 
@@ -508,11 +523,15 @@ class MeshBackend(ConsensusBackend):
     def shard_workers(self, x: Array) -> Array:
         spec = [None] * jnp.ndim(x)
         spec[0] = self.axis_name
-        return jax.device_put(x, NamedSharding(self.mesh, P(*spec)))
+        return _put(x, NamedSharding(self.mesh, P(*spec)))
+
+    @property
+    def replicated_sharding(self) -> NamedSharding:
+        return NamedSharding(self.mesh, P())
 
     def _place_replicated(self, replicated: tuple) -> tuple:
-        sharding = NamedSharding(self.mesh, P())
-        return tuple(jax.device_put(r, sharding) for r in replicated)
+        sharding = self.replicated_sharding
+        return tuple(_put(r, sharding) for r in replicated)
 
     # On a mesh, a collective-free fn is just a shard_map whose program
     # happens to contain no collectives — the same execution path, so

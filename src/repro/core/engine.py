@@ -58,6 +58,11 @@ class LayerStepResult(NamedTuple):
     jitter: "Array | None" = None
 
 
+#: Worker 0's slice of every stacked output, as one compiled program:
+#: op-by-op indexing costs the host more than the slice costs the device.
+_first_worker = jax.jit(lambda tree: jax.tree.map(lambda a: a[0], tree))
+
+
 def _propagate_and_stats(w, y_m, t_m, mu: float, use_kernels: bool):
     """relu(W @ Y_m) then (A_m, chol(G_m), jitter) — fused on aligned
     shapes; the Cholesky is the guarded (self-healing) factorization."""
@@ -247,11 +252,9 @@ def fused_layer_step(
         use_kernels=use_kernels, donate_y=donate_y, policy=policy,
         trace_every=trace_every,
     ).run()
-    trace = None
-    if traces is not None:
-        objs, primals, duals, cerrs = traces
-        trace = admm_lib.ADMMTrace(objs[0], primals[0], duals[0], cerrs[0])
+    o_star, traces = _first_worker((z_w, traces))
     return LayerStepResult(
-        o_star=z_w[0], o_workers=o_w, lam=lam_w, y_workers=y_next,
-        trace=trace, jitter=jitter_w,
+        o_star=o_star, o_workers=o_w, lam=lam_w, y_workers=y_next,
+        trace=None if traces is None else admm_lib.ADMMTrace(*traces),
+        jitter=jitter_w,
     )

@@ -14,12 +14,16 @@ claim of centralized equivalence.
 
 Execution: every layer runs through the compile-once layer engine
 (``core.engine``) — propagation, Gram/Cholesky and the K-iteration ADMM
-scan fuse into one cached SPMD program per layer, traces accumulate on
-device and are fetched once after the loop, and the self-size-estimation
-stop costs exactly one scalar fetch per layer.
+scan fuse into one cached SPMD program per layer, and W_{l+1} is built
+on the device by one more cached program.  Traces and the Cholesky
+jitter accumulate on the device and are fetched once after the loop, so
+by default the host queues every layer program without waiting on the
+device; the divergence guard, the self-size-estimation stop and
+checkpointing each wait once per layer.
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 import warnings
@@ -95,11 +99,13 @@ class _LoopState(NamedTuple):
 
     ``layer`` is the next layer to solve and ``y_workers`` the features
     it reads.  ``o``, ``traces`` and ``jitter`` hold one entry per
-    completed layer (the traces stay on the device until the loop ends).
-    ``r`` is the whole draw of random matrices, future layers included:
+    completed layer (the traces and the jitter stay on the device until
+    the loop ends).  ``r`` is the whole draw of random matrices, future
+    layers included, placed where W is built:
     divergence rollback perturbs the key for future layers, so the key
-    alone no longer determines them.  It is None only in a checkpoint
-    written before the random matrices were stored.
+    alone no longer determines them.  It is None in a checkpoint written
+    before the random matrices were stored, and in a fresh run until
+    layer 0's program is queued.
     """
 
     layer: int
@@ -113,13 +119,26 @@ class _LoopState(NamedTuple):
     r: tuple | None = None
 
 
-def _layer_weight(state: _LoopState, q: int) -> Array | None:
+@functools.lru_cache(maxsize=16)
+def _weight_program(sharding):
+    """``ssfn.build_weight`` as one compiled program whose output lands
+    in ``sharding`` (the backend's replicated operand; None = the
+    default device), so the layer program's placement moves nothing.
+    jit compiles it once per shape: one for layer 1 (R_1 reads the
+    input width), one for every deeper layer."""
+    kw = {} if sharding is None else {"out_shardings": sharding}
+    return jax.jit(ssfn_lib.build_weight, static_argnums=2, **kw)
+
+
+def _layer_weight(state: _LoopState, q: int, sharding) -> Array | None:
     """W_l = [V_Q O_{l-1} ; R_l] for the layer ``state`` stands before;
     None at layer 0, whose solve reads the input features directly."""
     if state.layer == 0:
         return None
     with profiling.span(profiling.BUILD_WEIGHT):
-        return ssfn_lib.build_weight(state.o[-1], state.r[state.layer - 1], q)
+        return _weight_program(sharding)(
+            state.o[-1], state.r[state.layer - 1], q
+        )
 
 
 def _save_checkpoint(
@@ -231,10 +250,27 @@ class LayerwiseLog:
     )
     #: Divergence-guard rollbacks taken during this run (0 = clean).
     rollbacks: int = 0
+    #: Times the loop waited on the device, each under a
+    #: ``dssfn.host_sync`` span: 1 by default (the fetch after the
+    #: loop), one more per layer solve when the divergence guard or the
+    #: size-estimation stop is on (they share one fetch), and one per
+    #: checkpoint saved.
+    host_syncs: int = 0
 
 
 def _mu_for_layer(cfg: ssfn_lib.SSFNConfig, layer: int) -> float:
     return cfg.mu0 if layer == 0 else cfg.mul
+
+
+def _read_layer(
+    step: engine_lib.LayerStepResult, prev_cost: float | None, guard: bool,
+) -> tuple[bool, float | None]:
+    """The scalars the divergence guard and the size-estimation stop read
+    after a layer solve: whether it diverged (False when unguarded) and
+    its consensus objective (None without traces)."""
+    diverged = guard and _step_diverged(step, prev_cost)
+    cost = None if step.trace is None else float(step.trace.objective[-1])
+    return diverged, cost
 
 
 def _step_diverged(
@@ -326,7 +362,9 @@ def train_decentralized_ssfn(
         matrix re-draws (the consumed ones are restored from the
         checkpoint verbatim — completed layers keep their exact
         weights), and retries — instead of crashing or silently
-        returning NaNs.  Costs one extra scalar fetch per layer.
+        returning NaNs.  Waits on the device once per layer, a fetch
+        shared with the size-estimation stop; without either, and
+        without checkpoints, the loop waits once, after the last layer.
     max_rollbacks: divergence-rollback budget; the run raises
         RuntimeError once a diverging layer has exhausted it.
     """
@@ -349,7 +387,22 @@ def train_decentralized_ssfn(
     policy = policy if policy is not None else engine_backend.policy
     num_workers = engine_backend.num_workers
     t_workers = engine_backend.shard_workers(t_workers)
+    sharding = engine_backend.replicated_sharding
     rollbacks = 0
+    host_syncs = 0
+
+    def synced(fn, *args, **kwargs):
+        """Run ``fn``, which waits on the device: one counted host sync."""
+        nonlocal host_syncs
+        host_syncs += 1
+        with profiling.span(profiling.HOST_SYNC):
+            return fn(*args, **kwargs)
+
+    def placed(r) -> tuple:
+        """Random matrices where W is built: one transfer per draw, so
+        no layer moves its R_l."""
+        r = tuple(r)
+        return r if sharding is None else jax.device_put(r, sharding)
 
     def restore(default: _LoopState | None, r: tuple | None = None):
         """Resume and rollback both restart here: the latest complete
@@ -366,10 +419,11 @@ def train_decentralized_ssfn(
         saved = _load_checkpoint(path)
         if saved.r is None:
             if r is None:
-                r = tuple(ssfn_lib.init_random_matrices(saved.key, cfg))
+                r = ssfn_lib.init_random_matrices(saved.key, cfg)
             saved = saved._replace(r=r)
         return saved._replace(
-            y_workers=engine_backend.shard_workers(saved.y_workers)
+            y_workers=engine_backend.shard_workers(saved.y_workers),
+            r=placed(saved.r),
         )
 
     # The divergence guard's restart point before the first checkpoint
@@ -381,14 +435,13 @@ def train_decentralized_ssfn(
             layer=0,
             key=key,
             y_workers=engine_backend.shard_workers(x_workers),   # y_0 = x
-            r=tuple(ssfn_lib.init_random_matrices(key, cfg)),
         )
 
     state = entry
     while state.layer <= cfg.num_layers:
         layer = state.layer
         with profiling.span(profiling.LAYER, layer=layer):
-            w = _layer_weight(state, q)
+            w = _layer_weight(state, q, sharding)
             with profiling.span(profiling.DISPATCH):
                 step = engine_lib.fused_layer_step(
                     engine_backend,
@@ -407,11 +460,21 @@ def train_decentralized_ssfn(
                     # layer 0's pass-through output may alias it.
                     donate_y=layer > 1,
                 )
+            if state.r is None:
+                # Layer 0 reads no R, so a fresh run draws R_1..R_L
+                # while the device runs it.
+                entry = state = state._replace(
+                    r=placed(ssfn_lib.init_random_matrices(state.key, cfg))
+                )
 
-            diverged = False
-            if guard_divergence:
-                with profiling.span(profiling.HOST_SYNC):
-                    diverged = _step_diverged(step, state.prev_cost)
+            # The guard and the size-estimation stop read this layer's
+            # scalars in one wait on the device; without them the loop
+            # queues the next layer at once.
+            diverged, cost = False, None
+            if guard_divergence or size_estimation_tol is not None:
+                diverged, cost = synced(
+                    _read_layer, step, state.prev_cost, guard_divergence
+                )
             if diverged:
                 if rollbacks >= max_rollbacks:
                     raise RuntimeError(
@@ -433,25 +496,23 @@ def train_decentralized_ssfn(
                 # r[0..layer-2] shaped the restored features and must stay
                 # verbatim.
                 key = jax.random.fold_in(state.key, 7 + rollbacks)
-                fresh = ssfn_lib.init_random_matrices(key, cfg)
+                fresh = placed(ssfn_lib.init_random_matrices(key, cfg))
                 first_free = max(state.layer - 1, 0)
                 state = state._replace(
                     key=key,
-                    r=state.r[:first_free] + tuple(fresh[first_free:]),
+                    r=state.r[:first_free] + fresh[first_free:],
                 )
                 continue
 
-            jitter = state.jitter
-            if step.jitter is not None:
-                with profiling.span(profiling.HOST_SYNC):
-                    jitter += (np.asarray(jax.device_get(step.jitter)),)
             state = state._replace(
                 layer=layer + 1,
                 o=state.o + (step.o_star,),
                 traces=state.traces + (
                     (step.trace,) if step.trace is not None else ()
                 ),
-                jitter=jitter,
+                jitter=state.jitter + (
+                    (step.jitter,) if step.jitter is not None else ()
+                ),
                 # Communication accounting, eq. 15: Q * n_{l-1} scalars per
                 # exchange, B exchanges per consensus, K communicating
                 # consensus rounds per layer — the policy itself knows its
@@ -469,40 +530,32 @@ def train_decentralized_ssfn(
             if checkpoint_dir is not None and (
                 stopping or state.layer % checkpoint_every == 0
             ):
-                with profiling.span(profiling.HOST_SYNC):
-                    _save_checkpoint(
-                        checkpoint_dir, state, step=step,
-                        active_mask=_active_mask(policy, num_workers),
-                    )
+                synced(
+                    _save_checkpoint, checkpoint_dir, state, step=step,
+                    active_mask=_active_mask(policy, num_workers),
+                )
             if stopping:
                 break
 
             # Self-size estimation: every worker sees the same consensus
-            # objective, so this stop decision is itself consensual.  It
-            # costs one scalar fetch per layer; without it (and without the
-            # guard) the jitter fetch above is the loop's only host sync.
-            if size_estimation_tol is not None:
-                with profiling.span(profiling.HOST_SYNC):
-                    cur = float(step.trace.objective[-1])
-                prev_cost = state.prev_cost
-                if (
-                    prev_cost is not None
-                    and prev_cost - cur < size_estimation_tol * max(prev_cost, 1e-12)
-                ):
-                    break
-                state = state._replace(prev_cost=cur)
-            elif guard_divergence and step.trace is not None:
-                # Track the layer cost so the guard's blow-up check has a
-                # reference even without size estimation.
-                with profiling.span(profiling.HOST_SYNC):
-                    state = state._replace(
-                        prev_cost=float(step.trace.objective[-1])
-                    )
+            # objective, so this stop decision is itself consensual.
+            prev_cost = state.prev_cost
+            if (
+                size_estimation_tol is not None
+                and prev_cost is not None
+                and prev_cost - cost < size_estimation_tol * max(prev_cost, 1e-12)
+            ):
+                break
+            if cost is not None:
+                # The stop's reference, and the guard's for its blow-up
+                # check.
+                state = state._replace(prev_cost=cost)
 
-    # One bulk fetch of every per-layer trace after the loop.  The
-    # collective-free hot path (trace_every=0) has none: the log carries
-    # empty (L+1, 0) trace arrays and no layer costs.
-    traces = [jax.tree.map(np.asarray, tr) for tr in state.traces]
+    # Without the guard, size estimation and checkpoints, the loop's one
+    # wait on the device: every layer's jitter and trace in one fetch.
+    # The collective-free hot path (trace_every=0) has no traces: the log
+    # carries empty (L+1, 0) trace arrays and no layer costs.
+    jitter, traces = synced(jax.device_get, (state.jitter, state.traces))
     layer_costs = [float(tr.objective[-1]) for tr in traces]
 
     def stacked(field: str) -> np.ndarray:
@@ -523,10 +576,10 @@ def train_decentralized_ssfn(
         wall_time_s=time.perf_counter() - t0,
         comm_scalars=state.comm,
         jitter_levels=(
-            np.stack(state.jitter)
-            if state.jitter else np.zeros((0, 0), np.int32)
+            np.stack(jitter) if jitter else np.zeros((0, 0), np.int32)
         ),
         rollbacks=rollbacks,
+        host_syncs=host_syncs,
     )
     return params, log
 

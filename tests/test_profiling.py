@@ -59,8 +59,8 @@ def test_train_emits_a_layer_span_per_layer(tmp_path):
     jax.block_until_ready(res.params.o)
     spans = _host_spans(tmp_path)
     assert [a["layer"] for a in spans[profiling.LAYER]] == list(range(layers + 1))
-    # The jitter fetch is the only per-layer sync of the default train.
-    assert len(spans[profiling.HOST_SYNC]) == layers + 1
+    # The default train waits on the device once, after its last layer.
+    assert len(spans[profiling.HOST_SYNC]) == 1
     assert len(spans[profiling.DISPATCH]) == layers + 1
     assert len(spans[profiling.BUILD_WEIGHT]) == layers
 
